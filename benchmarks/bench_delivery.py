@@ -920,7 +920,7 @@ def bench_serve(name, spec, results, *, trials=16, windows=4, batch=8,
 
     * ``batched`` -- the server with ``max_batch=batch``: folds up to
       ``batch`` trials into one block-diagonal dispatch against the
-      startup-warmed AOT executable.
+      startup-warmed executable.
     * ``sequential`` -- the server with ``max_batch=1``: identical
       machinery and warm executable, no folding (one dispatch per trial).
       Isolates the fold's per-window overhead amortisation, which on a
@@ -932,7 +932,7 @@ def bench_serve(name, spec, results, *, trials=16, windows=4, batch=8,
       per trial building its own engine and jit-compiling its own window
       (process startup and imports generously excluded; ``clear_caches``
       between trials stands in for process isolation). The server's
-      startup AOT warm amortises exactly this cost across every trial it
+      startup warm amortises exactly this cost across every trial it
       ever serves, and ``assert_speedup`` requires the batched server to
       clear 2x this baseline's throughput.
 
@@ -1142,10 +1142,13 @@ def main(argv=None) -> None:
 
     import jax
 
+    from repro.compile_cache import enable_compile_cache
     from repro.core.areas import (
         mam_benchmark_spec, mam_spec, ring_area_adjacency)
     from repro.core.connectivity import build_network
     from repro.kernels.ops import default_interpret
+
+    enable_compile_cache()
 
     results: list[dict] = []
     configs = [

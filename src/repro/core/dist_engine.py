@@ -48,14 +48,12 @@ bounds must be raised).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.areas import MultiAreaSpec
 from repro.core import connectivity as connectivity_lib
 from repro.core.connectivity import Network
@@ -571,7 +569,7 @@ def _make_dist_engine(
     else:
         block_spec = P(None, None, all_axes)
 
-    window_sm = shard_map(
+    window_sm = jax.shard_map(
         window_body,
         mesh=mesh,
         in_specs=(st_specs, nt_specs, gid_spec),
@@ -579,10 +577,14 @@ def _make_dist_engine(
         check_vma=False,
     )
 
-    gids_global = (
+    gids_global = jax.device_put(
         jnp.arange(A * n_pad, dtype=jnp.int32).reshape(A, n_pad)
-        if gids is None else gids
-    )
+        if gids is None else gids,
+        NamedSharding(mesh, gid_spec))
+    # Place the connectivity once with the schedule's shardings: the jitted
+    # entry points take it as an argument, so each device holds only its
+    # shard of the tables (already-placed leaves are left where they are).
+    net = shard_network(net, mesh, cfg.schedule)
 
     overlap_jit = drain_jit = init_inflight = None
     if cfg.overlap_exchange:
@@ -595,14 +597,14 @@ def _make_dist_engine(
         # shard_map'd program -- no SPMD deadlock risk from running it at a
         # host-decided boundary.
         if_specs = exchange.inflight_pspecs()
-        overlap_sm = shard_map(
+        overlap_sm = jax.shard_map(
             overlap_body,
             mesh=mesh,
             in_specs=(st_specs, if_specs, nt_specs, gid_spec),
             out_specs=(st_specs, if_specs, block_spec),
             check_vma=False,
         )
-        drain_sm = shard_map(
+        drain_sm = jax.shard_map(
             drain_body,
             mesh=mesh,
             in_specs=(st_specs, if_specs, nt_specs, gid_spec),
@@ -618,27 +620,21 @@ def _make_dist_engine(
             return jax.device_put(
                 exchange.init_inflight(net), inflight_shardings)
 
-        @jax.jit
-        def overlap_jit(state, inflight):
-            return overlap_sm(state, inflight, net, gids_global)
-
-        @jax.jit
-        def drain_jit(state, inflight):
-            return drain_sm(state, inflight, net, gids_global)
+        overlap_jit = schedule_lib.bind_network(overlap_sm, net, gids_global)
+        drain_jit = schedule_lib.bind_network(drain_sm, net, gids_global)
 
         # Compatibility `window`: one overlapped window drained on the spot
         # (finish of an empty inflight is a no-op) -- bit-identical to the
         # sequential window for every unpipelined caller.
-        @jax.jit
-        def window(state: SimState):
+        def window_drained(state: SimState, net, gids):
             st, inf, block = overlap_sm(
-                state, exchange.init_inflight(net), net, gids_global)
-            return drain_sm(st, inf, net, gids_global), block
+                state, exchange.init_inflight(net), net, gids)
+            return drain_sm(st, inf, net, gids), block
+
+        window = schedule_lib.bind_network(window_drained, net, gids_global)
 
     else:
-        @jax.jit
-        def window(state: SimState):
-            return window_sm(state, net, gids_global)
+        window = schedule_lib.bind_network(window_sm, net, gids_global)
 
     state_shardings = jax.tree.map(
         lambda s: NamedSharding(mesh, s), st_specs,
@@ -695,32 +691,33 @@ def _make_dist_engine(
         return shard_state(state)
 
     if cfg.overlap_exchange:
-        @functools.partial(jax.jit, static_argnums=1)
-        def run(state: SimState, n_windows: int):
+        def run_body(state: SimState, n_windows: int, net, gids):
             def step(carry, _):
                 st, inf = carry
-                st, inf, block = overlap_sm(st, inf, net, gids_global)
+                st, inf, block = overlap_sm(st, inf, net, gids)
                 return (st, inf), block.astype(jnp.int32).sum()
 
             (state, inf), spikes = jax.lax.scan(
                 step, (state, exchange.init_inflight(net)), None,
                 length=n_windows)
-            return drain_sm(state, inf, net, gids_global), spikes
+            return drain_sm(state, inf, net, gids), spikes
     else:
-        @functools.partial(jax.jit, static_argnums=1)
-        def run(state: SimState, n_windows: int):
+        def run_body(state: SimState, n_windows: int, net, gids):
             def step(st, _):
-                st, block = window_sm(st, net, gids_global)
+                st, block = window_sm(st, net, gids)
                 return st, block.astype(jnp.int32).sum()
 
             return jax.lax.scan(step, state, None, length=n_windows)
+
+    run = schedule_lib.bind_network(
+        run_body, net, gids_global, static_argnums=1)
 
     return Engine(init=init, window=window, run=run, config=cfg,
                   delay_ratio=D, window_raw=window_sm,
                   wire_bytes=exchange.wire_bytes(net),
                   shard_state=shard_state,
                   window_overlap=overlap_jit, drain=drain_jit,
-                  init_inflight=init_inflight)
+                  init_inflight=init_inflight, net=net)
 
 
 def make_dist_engine(

@@ -24,7 +24,6 @@ the assembled window. The per-cycle *deliver* hot path is backend-selectable
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Callable, NamedTuple
 
 import jax
@@ -180,6 +179,8 @@ class EngineConfig:
     # only the inter packet bound remains. Identical trajectories to the
     # unfused event engine are therefore guaranteed only while the unfused
     # engine reports overflow == 0 (its own exactness condition anyway).
+    # Rejected on a TPU: the TPU compiler refuses the kernel
+    # (kernels.cycle.TPU_REFUSAL), and it never runs interpreted there.
     superstep_kernel: bool = False
     # Double-buffer the structure-aware window-end exchange
     # (repro.core.exchange start_window_end/finish_window_end): window w's
@@ -285,6 +286,13 @@ class EngineConfig:
                     "superstep_kernel",
                     "superstep_kernel=True conflicts with superstep=False",
                     "drop one of the two flags"))
+            if jax.default_backend() == "tpu":
+                from repro.kernels.cycle import TPU_REFUSAL
+
+                v.append(ConfigViolation(
+                    "superstep_kernel", TPU_REFUSAL,
+                    "drop superstep_kernel (the jnp superstep fuses the "
+                    "same window)"))
         if self.overlap_exchange and self.schedule != STRUCTURE_AWARE:
             v.append(ConfigViolation(
                 "overlap_exchange",
@@ -399,6 +407,9 @@ class Engine(NamedTuple):
     # () -> an empty (scatters-nothing) InflightWindow on this engine's
     # devices: what the pipeline starts from and resets to after a drain.
     init_inflight: Callable | None = None
+    # The connectivity the jitted entry points run on, as placed on the
+    # device(s): every call passes it to the program as an argument.
+    net: Network | None = None
 
 
 def make_fused_lif_update(params: neuron_lib.LIFParams):
@@ -547,14 +558,8 @@ def _make_engine(
     if cfg.overlap_exchange:
         overlap_body, drain_body = schedule_lib.make_overlap_window_fn(
             cfg, exchange, update_fn, fused_superstep=fused_window)
-
-        @jax.jit
-        def overlap_jit(state, inflight):
-            return overlap_body(state, inflight, net, gids)
-
-        @jax.jit
-        def drain_jit(state, inflight):
-            return drain_body(state, inflight, net, gids)
+        overlap_jit = schedule_lib.bind_network(overlap_body, net, gids)
+        drain_jit = schedule_lib.bind_network(drain_body, net, gids)
 
         def init_inflight():
             return exchange.init_inflight(net)
@@ -562,16 +567,14 @@ def _make_engine(
         # The compatibility `window`: one overlapped window drained on the
         # spot -- bit-identical to the sequential window (finish of an empty
         # inflight is a no-op), so every unpipelined caller keeps working.
-        @jax.jit
-        def window(state: SimState) -> tuple[SimState, jax.Array]:
+        def window_body_drained(state, net, gids):
             st, inf, block = overlap_body(
                 state, exchange.init_inflight(net), net, gids)
             return drain_body(st, inf, net, gids), block
 
+        window = schedule_lib.bind_network(window_body_drained, net, gids)
     else:
-        @jax.jit
-        def window(state: SimState) -> tuple[SimState, jax.Array]:
-            return window_body(state, net, gids)
+        window = schedule_lib.bind_network(window_body, net, gids)
 
     def init(seed=None, stim=None) -> SimState:
         """Fresh state; optional per-neuron drive overrides (serving trials).
@@ -624,8 +627,7 @@ def _make_engine(
         # once at the end -- the jitted fast path actually runs start/finish
         # split across windows, so XLA's latency-hiding scheduler can move
         # the collectives off the critical path.
-        @functools.partial(jax.jit, static_argnums=1)
-        def run(state: SimState, n_windows: int):
+        def run_body(state: SimState, n_windows: int, net, gids):
             def body(carry, _):
                 st, inf = carry
                 st, inf, spikes = overlap_body(st, inf, net, gids)
@@ -636,19 +638,20 @@ def _make_engine(
                 length=n_windows)
             return drain_body(state, inf, net, gids), spikes
     else:
-        @functools.partial(jax.jit, static_argnums=1)
-        def run(state: SimState, n_windows: int):
+        def run_body(state: SimState, n_windows: int, net, gids):
             def body(st, _):
                 st, spikes = window_body(st, net, gids)
                 return st, spikes.sum(dtype=jnp.int32)
 
             return jax.lax.scan(body, state, None, length=n_windows)
 
+    run = schedule_lib.bind_network(run_body, net, gids, static_argnums=1)
+
     return Engine(
         init=init, window=window, run=run, config=cfg, delay_ratio=D,
         wire_bytes=exchange.wire_bytes(net),
         window_overlap=overlap_jit, drain=drain_jit,
-        init_inflight=init_inflight,
+        init_inflight=init_inflight, net=net,
     )
 
 

@@ -123,11 +123,16 @@ def deposit(
     [K x R] contraction per neuron tile (MXU/VPU friendly) instead of a serial
     scatter; the Pallas kernel in ``repro.kernels.spike_deliver`` implements
     the tiled version of exactly this contraction.
+
+    ``precision=HIGHEST``: at default precision a TPU contracts f32 in bf16
+    passes, which rounds 1/256-grid weights such as ~88 pA (15 significant
+    bits) and breaks the bitwise contract between backends.
     """
     r = ring.shape[-1]
     slots = jnp.mod(t + delays.astype(jnp.int32), r)  # [N, K]
     onehot = jax.nn.one_hot(slots, r, dtype=vals.dtype)  # [N, K, R]
-    return ring + jnp.einsum("nk,nkr->nr", vals, onehot)
+    return ring + jnp.einsum("nk,nkr->nr", vals, onehot,
+                             precision=jax.lax.Precision.HIGHEST)
 
 
 def deposit_scatter(

@@ -50,6 +50,7 @@ __all__ = [
     "SimState",
     "SimCheckpointer",
     "RunResult",
+    "bind_network",
     "make_update_fn",
     "make_window_fn",
     "make_overlap_window_fn",
@@ -98,6 +99,22 @@ class SimState:
     # request. None contributes no leaf; an all-ones array is bit-identical
     # to None (x * 1.0f is exact).
     stim: Any = None
+
+
+def bind_network(fn: Callable, net, gids, *, static_argnums=()) -> Callable:
+    """``jit(fn)`` with the trailing ``(net, gids)`` bound: ``f(*args) ->
+    fn(*args, net, gids)``.
+
+    The connectivity enters the program as *arguments*. Closed over, jit
+    would bake every synapse table into the executable as a constant --
+    gigabytes at chip scale, re-embedded for every compiled variant.
+    """
+    jitted = jax.jit(fn, static_argnums=static_argnums)
+
+    def bound(*args):
+        return jitted(*args, net, gids)
+
+    return bound
 
 
 def make_update_fn(

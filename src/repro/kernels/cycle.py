@@ -29,6 +29,9 @@ Grid: one program per area -- intra connectivity is area-local, so each
 program is self-contained. Sized for areas whose state + tables fit VMEM
 (the reference/benchmark scales); production-size areas would add an inner
 neuron tiling with a cross-tile spike exchange per cycle.
+
+Interpret mode only: the TPU compiler refuses both kernels (``TPU_REFUSAL``),
+and ``EngineConfig.validate`` rejects ``superstep_kernel`` on a TPU.
 """
 
 from __future__ import annotations
@@ -38,12 +41,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.neuron import counter_uniform
 from repro.kernels.lif_update import lif_step_math
 from repro.kernels.spike_deliver import delay_resolved_contrib
 
-__all__ = ["superstep_lif_pallas", "superstep_iaf_pallas"]
+__all__ = ["superstep_lif_pallas", "superstep_iaf_pallas", "TPU_REFUSAL"]
+
+# Why the TPU kernel compiler (Mosaic) refuses both kernels; pinned against
+# the compiler's own errors by tests/test_tpu_compile.py, and what
+# EngineConfig.validate reports for superstep_kernel on a TPU.
+TPU_REFUSAL = (
+    "Mosaic refuses the fused superstep kernels: the in-kernel intra "
+    "gather spk[src] is a 1-D gather ('Only 2D gather is supported'), the "
+    "LIF variant's in-kernel counter-based drive casts uint32 to float32 "
+    "('Unsupported cast: uint32 -> float32'), and a whole area's [n, K] "
+    "src/w/delay tables (12 B/synapse, 147 MB at n=4096, K=3000) cannot "
+    "stay VMEM-resident at published in-degrees")
 
 
 def _deposit_window(fut, spk, src, w, j, s: int, steps_lo: int, r_span: int):
@@ -64,17 +79,17 @@ def _lif_kernel(
     v_th: float, v_reset: float, t_ref_steps: int,
     seed: int, w_ext: float,
 ):
-    t0 = t0_ref[0]
+    t0 = t0_ref[0]                       # SMEM scalar (prefetched)
     v = v_ref[0]
     i_syn = i_ref[0]
     refrac = refrac_ref[0]
-    fut = fut_ref[0]                     # [n, W] live window slots, VMEM
+    fut = fut_ref[...]                   # [n, W] live window slots, VMEM
     p = p_ref[0]                         # per-cycle drive probability
     gids = gid_ref[0]
     alive = alive_ref[0] != 0
-    src = src_ref[0]
-    w = w_ref[0]
-    j = d_ref[0] - steps_lo
+    src = src_ref[...]
+    w = w_ref[...]
+    j = d_ref[...] - steps_lo
     for s in range(d_win):               # unrolled; every slot index static
         u = counter_uniform(seed, t0 + s, gids)
         drive = (u < p).astype(jnp.float32) * w_ext
@@ -83,12 +98,12 @@ def _lif_kernel(
             p11=p11, p21=p21, p22=p22, v_th=v_th, v_reset=v_reset,
             t_ref_steps=t_ref_steps,
         )
-        spk_out[0, s] = spk.astype(jnp.int8)
+        spk_out[s] = spk.astype(jnp.int32)
         fut = _deposit_window(fut, spk, src, w, j, s, steps_lo, r_span)
     v_out[0] = v
     i_out[0] = i_syn
     refrac_out[0] = refrac
-    fut_out[0] = fut
+    fut_out[...] = fut
 
 
 def _iaf_kernel(
@@ -97,27 +112,31 @@ def _iaf_kernel(
     *, d_win: int, steps_lo: int, r_span: int,
 ):
     cd = cd_ref[0]
-    fut = fut_ref[0]
+    fut = fut_ref[...]
     interval = interval_ref[0]
     alive = alive_ref[0] != 0
-    src = src_ref[0]
-    w = w_ref[0]
-    j = d_ref[0] - steps_lo
+    src = src_ref[...]
+    w = w_ref[...]
+    j = d_ref[...] - steps_lo
     for s in range(d_win):
         spk = (cd == 0) & alive
         cd = jnp.where(spk, interval - 1, cd - 1)
-        spk_out[0, s] = spk.astype(jnp.int8)
+        spk_out[s] = spk.astype(jnp.int32)
         fut = _deposit_window(fut, spk, src, w, j, s, steps_lo, r_span)
     cd_out[0] = cd
-    fut_out[0] = fut
+    fut_out[...] = fut
 
 
-def _specs(a: int, n: int, k: int, w_width: int, d_win: int):
-    """BlockSpecs shared by both variants: one area per grid step."""
-    row = pl.BlockSpec((1, n), lambda i: (i, 0))
-    fut = pl.BlockSpec((1, n, w_width), lambda i: (i, 0, 0))
-    syn = pl.BlockSpec((1, n, k), lambda i: (i, 0, 0))
-    spk = pl.BlockSpec((1, d_win, n), lambda i: (i, 0, 0))
+def _specs(n: int, k: int, w_width: int, d_win: int):
+    """BlockSpecs shared by both variants: one area per grid step, the area
+    axis squeezed (``None``) so every block's last two dims are whole array
+    dims. Per-neuron rows travel as ``[A, 1, n]``: a squeezed second-minor
+    dim of a 2-D ``[A, n]`` array is not a legal TPU block. Index maps take
+    ``*_`` for the scalar-prefetch refs."""
+    row = pl.BlockSpec((None, 1, n), lambda i, *_: (i, 0, 0))
+    fut = pl.BlockSpec((None, n, w_width), lambda i, *_: (i, 0, 0))
+    syn = pl.BlockSpec((None, n, k), lambda i, *_: (i, 0, 0))
+    spk = pl.BlockSpec((None, d_win, n), lambda i, *_: (i, 0, 0))
     return row, fut, syn, spk
 
 
@@ -135,7 +154,7 @@ def superstep_lif_pallas(
     fut: jax.Array,      # [A, n, W] f32 live window slots (rel [0, W))
     drive_p: jax.Array,  # [A, n] f32 per-cycle Bernoulli drive probability
     gids: jax.Array,     # [A, n] int32 global neuron ids (drive counter)
-    alive: jax.Array,    # [A, n] int8
+    alive: jax.Array,    # [A, n] int32
     src: jax.Array,      # [A, n, K] int32 intra sources (within-area index)
     w: jax.Array,        # [A, n, K] f32
     delay: jax.Array,    # [A, n, K] int32
@@ -149,31 +168,38 @@ def superstep_lif_pallas(
     seed: int, w_ext: float,
     interpret: bool = True,
 ):
-    """Fused LIF window: returns ``(v, i_syn, refrac, fut, spikes[A, D, n])``."""
+    """Fused LIF window: returns ``(v, i_syn, refrac, fut, spikes[A, D, n])``
+    with int32 spikes. ``t0`` rides in SMEM as a scalar-prefetch operand."""
     a, n = v.shape
     w_width = fut.shape[-1]
     k = src.shape[-1]
-    row, futs, syn, spks = _specs(a, n, k, w_width, d_win)
-    t0s = pl.BlockSpec((1,), lambda i: (0,))
+    row, futs, syn, spks = _specs(n, k, w_width, d_win)
     kernel = functools.partial(
         _lif_kernel, d_win=d_win, steps_lo=steps_lo, r_span=r_span,
         p11=p11, p21=p21, p22=p22, v_th=v_th, v_reset=v_reset,
         t_ref_steps=t_ref_steps, seed=seed, w_ext=w_ext,
     )
-    return pl.pallas_call(
+    rows = lambda x: x.reshape(a, 1, n)
+    v, i_syn, refrac, fut, spk = pl.pallas_call(
         kernel,
-        grid=(a,),
-        in_specs=[t0s, row, row, row, futs, row, row, row, syn, syn, syn],
-        out_specs=(row, row, row, futs, spks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(a,),
+            in_specs=[row, row, row, futs, row, row, row, syn, syn, syn],
+            out_specs=(row, row, row, futs, spks),
+        ),
         out_shape=(
-            jax.ShapeDtypeStruct((a, n), v.dtype),
-            jax.ShapeDtypeStruct((a, n), i_syn.dtype),
-            jax.ShapeDtypeStruct((a, n), jnp.int32),
+            jax.ShapeDtypeStruct((a, 1, n), v.dtype),
+            jax.ShapeDtypeStruct((a, 1, n), i_syn.dtype),
+            jax.ShapeDtypeStruct((a, 1, n), jnp.int32),
             jax.ShapeDtypeStruct((a, n, w_width), fut.dtype),
-            jax.ShapeDtypeStruct((a, d_win, n), jnp.int8),
+            jax.ShapeDtypeStruct((a, d_win, n), jnp.int32),
         ),
         interpret=interpret,
-    )(t0, v, i_syn, refrac, fut, drive_p, gids, alive, src, w, delay)
+    )(t0, rows(v), rows(i_syn), rows(refrac), fut, rows(drive_p),
+      rows(gids), rows(alive), src, w, delay)
+    return (v.reshape(a, n), i_syn.reshape(a, n), refrac.reshape(a, n),
+            fut, spk)
 
 
 @functools.partial(
@@ -184,7 +210,7 @@ def superstep_iaf_pallas(
     countdown: jax.Array,  # [A, n] int32
     fut: jax.Array,        # [A, n, W] f32
     interval: jax.Array,   # [A, n] int32 firing interval (steps)
-    alive: jax.Array,      # [A, n] int8
+    alive: jax.Array,      # [A, n] int32
     src: jax.Array,        # [A, n, K] int32
     w: jax.Array,          # [A, n, K] f32
     delay: jax.Array,      # [A, n, K] int32
@@ -194,22 +220,25 @@ def superstep_iaf_pallas(
     r_span: int,
     interpret: bool = True,
 ):
-    """Fused ignore-and-fire window: ``(countdown, fut, spikes[A, D, n])``."""
+    """Fused ignore-and-fire window: ``(countdown, fut, spikes[A, D, n])``
+    with int32 spikes."""
     a, n = countdown.shape
     w_width = fut.shape[-1]
     k = src.shape[-1]
-    row, futs, syn, spks = _specs(a, n, k, w_width, d_win)
+    row, futs, syn, spks = _specs(n, k, w_width, d_win)
     kernel = functools.partial(
         _iaf_kernel, d_win=d_win, steps_lo=steps_lo, r_span=r_span)
-    return pl.pallas_call(
+    rows = lambda x: x.reshape(a, 1, n)
+    cd, fut, spk = pl.pallas_call(
         kernel,
         grid=(a,),
         in_specs=[row, futs, row, row, syn, syn, syn],
         out_specs=(row, futs, spks),
         out_shape=(
-            jax.ShapeDtypeStruct((a, n), jnp.int32),
+            jax.ShapeDtypeStruct((a, 1, n), jnp.int32),
             jax.ShapeDtypeStruct((a, n, w_width), fut.dtype),
-            jax.ShapeDtypeStruct((a, d_win, n), jnp.int8),
+            jax.ShapeDtypeStruct((a, d_win, n), jnp.int32),
         ),
         interpret=interpret,
-    )(countdown, fut, interval, alive, src, w, delay)
+    )(rows(countdown), fut, rows(interval), rows(alive), src, w, delay)
+    return cd.reshape(a, n), fut, spk

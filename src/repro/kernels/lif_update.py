@@ -4,8 +4,10 @@ The paper's *update* phase is one of the three per-cycle compute phases
 (Fig. 3). A naive jnp chain (decay -> integrate -> threshold -> reset ->
 refractory bookkeeping) makes ~6 HBM round trips over the state arrays; this
 kernel fuses them into one pass: each [TILE] block of neuron state is loaded
-into VMEM once, updated, and written once. The state layout is a flat [N]
-vector (the engines flatten [A, n_pad]), padded to the tile size.
+into VMEM once, updated, and written once. The state is laid out as
+``[N / 128, 128]`` rows (the engines flatten [A, n_pad] and pad to the tile
+size), so every block is lane-aligned; ``alive`` and the spike output travel
+as int32 because Mosaic cannot retile 1-D or sub-(32, 128) int8 blocks.
 
 VPU-bound, so the tile is sized in (8 x 128) register-file multiples.
 """
@@ -18,11 +20,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["lif_update_pallas", "lif_step_math", "TILE"]
+__all__ = ["lif_update_pallas", "lif_step_math", "LANES", "TILE"]
 
+LANES = 128
 # 8 sublanes x 128 lanes x 8 = one comfortably VMEM-resident f32 block per
-# state array (6 arrays live at once: v, i_syn, refrac, i_in, alive + outs).
-TILE = 8 * 128 * 8
+# state array (9 arrays live at once: v, i_syn, refrac, i_in, alive + outs).
+TILE = 8 * LANES * 8
 
 
 def lif_step_math(
@@ -64,7 +67,7 @@ def _kernel(
     v_out_ref[...] = v_out
     i_out_ref[...] = i_out
     refrac_out_ref[...] = refrac_out
-    spike_out_ref[...] = spikes.astype(jnp.int8)
+    spike_out_ref[...] = spikes.astype(jnp.int32)
 
 
 @functools.partial(
@@ -79,7 +82,7 @@ def lif_update_pallas(
     i_syn: jax.Array,
     refrac: jax.Array,
     i_in: jax.Array,
-    alive: jax.Array,  # int8 (0/1)
+    alive: jax.Array,  # int32 (0/1)
     *,
     p11: float,
     p21: float,
@@ -90,27 +93,33 @@ def lif_update_pallas(
     tile: int = TILE,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Fused LIF step over flat [N] state. N must be a multiple of ``tile``
-    (use :func:`repro.kernels.ops.lif_update` for automatic padding)."""
-    n = v.shape[0]
-    if n % tile != 0:
-        raise ValueError(f"N={n} must be a multiple of tile={tile}")
-    grid = (n // tile,)
-    bs = pl.BlockSpec((tile,), lambda i: (i,))
+    """Fused LIF step over ``[N / 128, 128]`` state; returns int32 spikes.
+
+    ``tile`` (neurons per grid step) must be a multiple of 8 x 128 and
+    divide N (use :func:`repro.kernels.ops.lif_update` for automatic
+    flattening and padding)."""
+    rows = v.shape[0]
+    tile_rows = tile // LANES
+    if v.shape[1:] != (LANES,) or tile % (8 * LANES) or rows % tile_rows:
+        raise ValueError(
+            f"state {v.shape} must be [N / {LANES}, {LANES}] with N a "
+            f"multiple of tile={tile} (a multiple of {8 * LANES})")
+    bs = pl.BlockSpec((tile_rows, LANES), lambda i: (i, 0))
     kernel = functools.partial(
         _kernel, p11=p11, p21=p21, p22=p22,
         v_th=v_th, v_reset=v_reset, t_ref_steps=t_ref_steps,
     )
+    shape = (rows, LANES)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(rows // tile_rows,),
         in_specs=[bs] * 5,
         out_specs=(bs, bs, bs, bs),
         out_shape=(
-            jax.ShapeDtypeStruct((n,), v.dtype),
-            jax.ShapeDtypeStruct((n,), i_syn.dtype),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.int8),
+            jax.ShapeDtypeStruct(shape, v.dtype),
+            jax.ShapeDtypeStruct(shape, i_syn.dtype),
+            jax.ShapeDtypeStruct(shape, jnp.int32),
+            jax.ShapeDtypeStruct(shape, jnp.int32),
         ),
         interpret=interpret,
     )(v, i_syn, refrac, i_in, alive)

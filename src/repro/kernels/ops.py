@@ -158,18 +158,20 @@ def lif_update(
     v, i_syn, refrac, i_in, alive,
     *, p11, p21, p22, v_th, v_reset, t_ref_steps, tile: int | None = None,
 ):
-    """Fused LIF step over arbitrary-shape state (flattens + pads)."""
+    """Fused LIF step over arbitrary-shape state (flattens, pads, and lays
+    the neurons out as lane-aligned ``[N / 128, 128]`` rows)."""
     shape = v.shape
-    tile = tile or min(_lif.TILE, max(128, v.size))
-    flat = lambda x: _pad_to(x.reshape(-1), tile)
+    block = 8 * _lif.LANES
+    tile = tile or min(_lif.TILE, -(-v.size // block) * block)
+    flat = lambda x: _pad_to(x.reshape(-1), tile).reshape(-1, _lif.LANES)
     v_o, i_o, r_o, s_o = _lif.lif_update_pallas(
         flat(v), flat(i_syn), flat(refrac), flat(i_in),
-        flat(alive.astype(jnp.int8)),
+        flat(alive.astype(jnp.int32)),
         p11=p11, p21=p21, p22=p22, v_th=v_th, v_reset=v_reset,
         t_ref_steps=t_ref_steps, tile=tile, interpret=default_interpret(),
     )
     n = v.size
-    unflat = lambda x: x[:n].reshape(shape)
+    unflat = lambda x: x.reshape(-1)[:n].reshape(shape)
     return unflat(v_o), unflat(i_o), unflat(r_o), unflat(s_o) != 0
 
 
@@ -179,14 +181,15 @@ def spike_deliver(
 ):
     """Delay-resolved contributions [N, r_span] for arbitrary N (pads rows)."""
     n = src.shape[0]
-    tile_n = tile_n or min(_dlv.TILE_N, n)
-    src_p = _pad_to(src, tile_n)
-    w_p = _pad_to(w, tile_n)
+    tile_n = tile_n or min(_dlv.TILE_N, -(-n // 8) * 8)
+    # The source gather stays in XLA (Mosaic has no 1-D gather); padded rows
+    # carry value 0 and so deposit nothing.
+    vals_p = _pad_to(w * spikes[src], tile_n)
     # Delays may be stored int8 (connectivity's narrow dtype); the kernel
-    # indexes with them, so widen at the boundary.
+    # compares them as int32, so widen at the boundary.
     d_p = _pad_to(delay.astype(jnp.int32), tile_n, value=steps_lo)
     out = _dlv.spike_deliver_pallas(
-        spikes, src_p, w_p, d_p,
+        vals_p, d_p,
         steps_lo=steps_lo, r_span=r_span, tile_n=tile_n,
         interpret=default_interpret(),
     )
@@ -298,13 +301,13 @@ def superstep_lif(
 
     Wraps :func:`repro.kernels.cycle.superstep_lif_pallas`: padded rows carry
     ``alive=0`` / ``w=0`` so they never spike and never deposit. Returns
-    ``(v, i_syn, refrac, fut, spikes [A, d_win, n] int8)``.
+    ``(v, i_syn, refrac, fut, spikes [A, d_win, n] int32)``.
     """
     n = v.shape[1]
     tile = 128 if n >= 128 else 8
     xs = _pad_rows(
         dict(v=v, i_syn=i_syn, refrac=refrac, fut=fut, drive_p=drive_p,
-             gids=gids, alive=alive.astype(jnp.int8), src=src, w=w,
+             gids=gids, alive=alive.astype(jnp.int32), src=src, w=w,
              delay=delay.astype(jnp.int32)),
         tile, fills=dict(delay=steps_lo),
     )
@@ -330,7 +333,7 @@ def superstep_iaf(
     tile = 128 if n >= 128 else 8
     xs = _pad_rows(
         dict(countdown=countdown, fut=fut, interval=interval,
-             alive=alive.astype(jnp.int8), src=src, w=w,
+             alive=alive.astype(jnp.int32), src=src, w=w,
              delay=delay.astype(jnp.int32)),
         tile, fills=dict(delay=steps_lo, interval=1),
     )

@@ -64,4 +64,5 @@ def spike_deliver_ref(
     vals = w * spikes[src]  # [N, K]
     j = delay - steps_lo    # [N, K], target slot offset
     onehot = jax.nn.one_hot(j, r_span, dtype=vals.dtype)  # [N, K, r_span]
-    return jnp.einsum("nk,nkr->nr", vals, onehot)
+    return jnp.einsum("nk,nkr->nr", vals, onehot,
+                      precision=jax.lax.Precision.HIGHEST)

@@ -5,12 +5,14 @@ its irregular memory access is the subject of the §2.3 cache model. NEST walks
 per-synapse pointer chains; the TPU-native rethink is dense and delay-resolved:
 
 * connectivity is rectangular ``src/w/delay [N, K]`` (fixed in-degree),
-* a grid over target tiles keeps each ``[TILE_N, K]`` synapse block in VMEM
-  together with the *entire* source spike vector (1 f32/neuron -- even a full
-  131k-neuron area is 512 KiB),
+* the source gather ``w * spk[src]`` runs in XLA (Mosaic lowers only 2-D
+  gathers, and a ``[TILE_N, K]`` index into a 1-D spike vector is not one),
+  so the kernel receives the per-synapse values ``vals [N, K]``,
+* a grid over target tiles keeps each ``[TILE_N, K]`` block of values and
+  delays in VMEM,
 * for each delay slot ``j`` in the compile-time window ``[steps_lo,
-  steps_lo + r_span)`` the kernel reduces ``w * spk[src] * [delay == j]`` over
-  K in one VPU pass, emitting ``contrib[TILE_N, r_span]``.
+  steps_lo + r_span)`` the kernel reduces ``vals * [delay == j]`` over K in
+  one VPU pass, emitting ``contrib[TILE_N, r_span]``.
 
 The engine then rolls ``contrib`` into the ring buffer at
 ``slot = (t + steps_lo + j) % R``. The separation of *intra* and *inter*
@@ -40,30 +42,26 @@ def delay_resolved_contrib(vals, j, r_span: int):
     ``vals [N, K]`` are the per-synapse contributions (w * spike), ``j [N, K]``
     the slot offsets in ``[0, r_span)``. One reduction over K per slot;
     ``r_span`` is a small compile-time constant (per-pathway delay width), so
-    this unrolls into r_span masked row-sums -- no MXU, pure VPU. Shared by
-    this kernel and the fused superstep kernel (:mod:`repro.kernels.cycle`).
+    this unrolls into r_span masked row-sums -- no MXU, pure VPU. The row
+    sums stay ``[N, 1]`` and are concatenated along lanes: a stack of 1-D
+    sums needs a relayout Mosaic does not do. Shared by this kernel and the
+    fused superstep kernel (:mod:`repro.kernels.cycle`).
     """
-    cols = []
-    for r in range(r_span):
-        cols.append(jnp.sum(jnp.where(j == r, vals, 0.0), axis=1))
-    return jnp.stack(cols, axis=1)
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(j == r, vals, 0.0), axis=1, keepdims=True)
+         for r in range(r_span)], axis=1)
 
 
-def _kernel(spk_ref, src_ref, w_ref, d_ref, out_ref, *, steps_lo: int, r_span: int):
-    spk = spk_ref[...]            # [N_src] f32, whole source vector in VMEM
-    idx = src_ref[...]            # [TILE_N, K]
-    vals = w_ref[...] * spk[idx]  # gather + scale, one VPU pass
+def _kernel(vals_ref, d_ref, out_ref, *, steps_lo: int, r_span: int):
     j = d_ref[...] - steps_lo     # slot offsets in [0, r_span)
-    out_ref[...] = delay_resolved_contrib(vals, j, r_span)
+    out_ref[...] = delay_resolved_contrib(vals_ref[...], j, r_span)
 
 
 @functools.partial(
     jax.jit, static_argnames=("steps_lo", "r_span", "tile_n", "interpret")
 )
 def spike_deliver_pallas(
-    spikes: jax.Array,  # [N_src] f32
-    src: jax.Array,     # [N, K] int32
-    w: jax.Array,       # [N, K] f32
+    vals: jax.Array,    # [N, K] f32 per-synapse values w * spk[src]
     delay: jax.Array,   # [N, K] int32
     *,
     steps_lo: int,
@@ -73,25 +71,20 @@ def spike_deliver_pallas(
 ) -> jax.Array:
     """Delay-resolved delivery contributions ``[N, r_span]``.
 
-    N must be a multiple of ``tile_n`` (use ops.spike_deliver for padding).
-    Semantics match :func:`repro.kernels.ref.spike_deliver_ref`.
+    N must be a multiple of ``tile_n`` (use ops.spike_deliver, which also
+    does the source gather). Semantics match
+    :func:`repro.kernels.ref.spike_deliver_ref`.
     """
-    n, k = src.shape
+    n, k = vals.shape
     if n % tile_n != 0:
         raise ValueError(f"N={n} must be a multiple of tile_n={tile_n}")
-    n_src = spikes.shape[0]
-    grid = (n // tile_n,)
     kernel = functools.partial(_kernel, steps_lo=steps_lo, r_span=r_span)
+    syn = pl.BlockSpec((tile_n, k), lambda i: (i, 0))
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n_src,), lambda i: (0,)),       # full spike vector
-            pl.BlockSpec((tile_n, k), lambda i: (i, 0)),  # synapse tiles
-            pl.BlockSpec((tile_n, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile_n, k), lambda i: (i, 0)),
-        ],
+        grid=(n // tile_n,),
+        in_specs=[syn, syn],
         out_specs=pl.BlockSpec((tile_n, r_span), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, r_span), w.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, r_span), vals.dtype),
         interpret=interpret,
-    )(spikes, src, w, delay)
+    )(vals, delay)

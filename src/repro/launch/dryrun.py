@@ -1,8 +1,14 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# The dry-run compiles for 512 forced host devices and never touches an
+# accelerator, so it pins the CPU platform (on a TPU host it would otherwise
+# build its meshes from the chips). The flags are appended, not overwritten.
+_USER_XLA_FLAGS = os.environ.get("XLA_FLAGS", "")
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (
+    _USER_XLA_FLAGS + " --xla_force_host_platform_device_count=512").strip()
 
-# ^ MUST be the first two lines, before ANY other import (jax locks the device
-# count at first initialisation). Everything below is ordinary.
+# ^ MUST run before ANY other import (jax locks the platform and device count
+# at first initialisation). Everything below is ordinary.
 
 """Multi-pod dry-run: lower + compile every (architecture x shape x mesh) cell.
 
@@ -39,8 +45,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro import compat
 
 from repro.configs.common import SHAPES, ShapeSpec
 from repro.configs.registry import arch_skips, get_arch, list_archs
@@ -189,7 +193,7 @@ def dryrun_lm_cell(arch_id: str, shape_name: str, multi_pod: bool,
     fsdp_axis = "data" if bundle.cfg.param_count() >= 1e9 else None
     t0 = time.time()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             hier_cfg = None
             if hierarchical and multi_pod:
@@ -423,8 +427,10 @@ def measure_build_rss(
     )
 
     def _peak_kib(src: str) -> int:
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)  # no forced 512-device init in children
+        # Children inherit JAX_PLATFORMS=cpu (the builds call jnp, and a
+        # child must never reach for a chip this process could hold) but
+        # not the forced 512-device flag.
+        env = dict(os.environ, XLA_FLAGS=_USER_XLA_FLAGS)
         out = subprocess.run(
             [sys.executable, "-c", src], capture_output=True, text=True,
             env=env, check=True)
@@ -593,7 +599,7 @@ def dryrun_snn_cell(
     gids_sds = shard(sds((A, n_pad), jnp.int32), gid_spec)
 
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(eng.window_raw).lower(state_sds, net_in, gids_sds)
         compiled = lowered.compile()
     row.update(_analyze(lowered, compiled, n_devices))

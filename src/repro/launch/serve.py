@@ -22,14 +22,13 @@ the *single-trial* code shape. How much of the window that amortises is
 host-dependent: on accelerators the fixed per-dispatch cost dominates
 small windows; on a single-core CPU host per-neuron compute dominates
 and the fold's warm-loop gain is small. The serving layer's headline
-throughput win there is the startup AOT warm instead -- every tenant
+throughput win there is the startup warm instead -- every tenant
 shares one compiled executable rather than paying engine build + jit
 compile per trial (>=2x over per-trial cold clients is the benchmarked
 floor; see ``benchmarks/bench_delivery.py::bench_serve``).
 
-**Execution model.** At startup the server builds the folded engine,
-AOT-compiles its window executable (``Engine.window.lower(...).compile()``)
-and warms it with a filler batch. One *executor* thread owns all device
+**Execution model.** At startup the server builds the folded engine and
+compiles and warms its window executable with a filler batch. One *executor* thread owns all device
 work (one host process drives one device queue; submitters are free to be
 many): it groups queued requests by duration bucket (a power-of-two ladder
 of window counts), assembles the per-copy drive leaves, and advances the
@@ -67,6 +66,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.areas import MultiAreaSpec, tile_spec
 from repro.core import connectivity as connectivity_lib
 from repro.core.engine import EngineConfig
@@ -248,16 +248,14 @@ class SimServer:
     # lifecycle
 
     def start(self) -> "SimServer":
-        """AOT-compile + warm the window executable, start the executor."""
+        """Compile + warm the window executable, start the executor."""
         st = self._init_state(
             [TrialRequest(seed=int(self.config.seed))] )
         # One window executable serves every duration bucket (the windowed
         # executor streams blocks; a fixed-length scan would return only
-        # spike counts). AOT-compile it for the folded state shape, then
-        # warm with one real dispatch so the first tenant never pays
-        # compile or first-touch cost.
-        compiled = self.engine.window.lower(st).compile()
-        self.engine = self.engine._replace(window=compiled)
+        # spike counts). Compile it for the folded state shape with one
+        # real dispatch so the first tenant never pays compile or
+        # first-touch cost.
         out_st, _ = self.engine.window(st)
         import jax
         jax.block_until_ready(out_st.ring)
@@ -493,6 +491,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="CI smoke: mixed batch, assert bitwise equality "
                          "to sequential references and nonzero trials/s")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.core.neuron import LIFParams
 

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.areas import mam_benchmark_spec, mam_spec
 from repro.core.connectivity import area_adjacency, build_network
 from repro.core.engine import EngineConfig
@@ -528,6 +529,7 @@ def main() -> None:
                     help="write the final per-neuron spike_count to this "
                          ".npz (CI resume-equality checks)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # --compare-overlap + a jitter-only fault spec is the one sanctioned
     # fault/compare combination: every leg runs the fault harness with the

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 __all__ = ["pipeline_apply"]
 
@@ -82,7 +81,7 @@ def pipeline_apply(
         return out
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         run, mesh=mesh,
         in_specs=(spec_params, P()),
         out_specs=P(),

@@ -66,7 +66,6 @@ def test_gather_paths_packed_vs_unpacked_agree():
     print(_run("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from repro.compat import shard_map
         from repro.core import comm
 
         mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
@@ -94,7 +93,7 @@ def test_gather_paths_packed_vs_unpacked_agree():
 
             spk = jnp.asarray(
                 rng.integers(0, 2, (A_loc * 4, 2 * n_loc)), jnp.int8)
-            fa = shard_map(body_area, mesh=mesh,
+            fa = jax.shard_map(body_area, mesh=mesh,
                            in_specs=P(("pod", "data"), "model"),
                            out_specs=(P(("pod", "data"), None),
                                       P(("pod", "data"), None)),
@@ -104,7 +103,7 @@ def test_gather_paths_packed_vs_unpacked_agree():
 
             blk = jnp.asarray(
                 rng.integers(0, 2, (D, A_loc * 4, 2 * n_loc)), jnp.int8)
-            fg = shard_map(body_global, mesh=mesh,
+            fg = jax.shard_map(body_global, mesh=mesh,
                            in_specs=P(None, ("pod", "data"), "model"),
                            out_specs=(P(None, None, None),
                                       P(None, None, None)),
@@ -114,7 +113,7 @@ def test_gather_paths_packed_vs_unpacked_agree():
 
             spk2 = jnp.asarray(
                 rng.integers(0, 2, (A_loc, 8 * n_loc)), jnp.int8)
-            ff = shard_map(body_full, mesh=mesh,
+            ff = jax.shard_map(body_full, mesh=mesh,
                            in_specs=P(None, ("pod", "data", "model")),
                            out_specs=(P(None, None), P(None, None)),
                            check_vma=False)

@@ -192,7 +192,6 @@ def test_moe_expert_parallel_lowering():
     print(_run("""
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro import compat
         from repro.models.moe import MoEConfig, moe_apply, moe_init, moe_pspecs
 
         mesh = jax.make_mesh((2, 4), ("data", "model"))
@@ -205,7 +204,7 @@ def test_moe_expert_parallel_lowering():
         x = jax.device_put(
             jax.random.normal(jax.random.PRNGKey(1), (4, 16, 16)),
             NamedSharding(mesh, P("data", None, None)))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y, aux = jax.jit(lambda p, x: moe_apply(p, x, cfg))(p, x)
         assert y.shape == x.shape
         print("OK")
@@ -216,7 +215,6 @@ def test_pipeline_parallel_matches_sequential():
     """GPipe wrapper == sequential stage application (4-stage pipe)."""
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.train.pipeline import pipeline_apply
 
         mesh = jax.make_mesh((4,), ("pipe",))
@@ -229,7 +227,7 @@ def test_pipeline_parallel_matches_sequential():
             return jnp.tanh(x @ p["w"])
 
         x = jax.random.normal(jax.random.PRNGKey(1), (M, mb, d))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = pipeline_apply(stage, params, x, mesh)
         # sequential reference
         ref = x

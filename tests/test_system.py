@@ -336,19 +336,25 @@ def test_event_overflow_counter_reports_drops():
 
 def test_fused_lif_update_matches_jnp_chain():
     """The fused Pallas LIF kernel is a drop-in for the jnp update chain:
-    bit-identical trajectories under every backend."""
-    spec = mam_benchmark_spec(n_areas=4, n_per_area=48, k_intra=8, k_inter=8)
+    bit-identical trajectories -- spikes and the v / i_syn / refrac state
+    after every window -- on both schedules."""
+    spec = mam_benchmark_spec(n_areas=4, n_per_area=48, k_intra=8, k_inter=8,
+                              rate_hz=30.0)
     net = build_network(spec, seed=12)
-    plain = make_simulation(spec, EngineConfig(
-        neuron_model="lif", delivery_backend="scatter", fused_update=False), net=net)
-    fused = make_simulation(spec, EngineConfig(
-        neuron_model="lif", delivery_backend="scatter", fused_update=True), net=net)
-    sp, sf = plain.init(), fused.init()
-    for w in range(30):
-        sp, blk_p = plain.window(sp)
-        sf, blk_f = fused.window(sf)
-        assert np.array_equal(np.asarray(blk_p), np.asarray(blk_f)), w
-    assert int(sp.spike_count.sum()) > 0, "LIF must spike within 30 ms"
+    for schedule in ("conventional", "structure_aware"):
+        plain, fused = (make_simulation(spec, EngineConfig(
+            neuron_model="lif", delivery_backend="scatter", schedule=schedule,
+            fused_update=f), net=net) for f in (False, True))
+        sp, sf = plain.init(), fused.init()
+        for w in range(30):
+            sp, blk_p = plain.window(sp)
+            sf, blk_f = fused.window(sf)
+            assert np.array_equal(np.asarray(blk_p), np.asarray(blk_f)), w
+            for field in ("v", "i_syn", "refrac"):
+                assert np.array_equal(
+                    np.asarray(getattr(sp.neuron, field)),
+                    np.asarray(getattr(sf.neuron, field))), (schedule, w, field)
+        assert int(sp.spike_count.sum()) > 0, "LIF must spike within 30 ms"
 
 
 def test_network_delay_window_metadata():
