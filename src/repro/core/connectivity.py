@@ -26,6 +26,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.areas import MultiAreaSpec
 from repro.core.partition import shard_pathway_rows
@@ -632,46 +633,51 @@ def build_network(
     # excitatory/inhibitory by source index on the 1/256 grid; delays on
     # the dt grid with tiered cutoffs (eq. (1) and §4.2). All draws are
     # the shared counter-based row functions.
-    s_, w_, d_ = _intra_rows(spec, seed, rows, n_pad, sizes)
-    src_intra = s_.reshape(A, n_pad, K_i)
-    w_intra = w_.reshape(A, n_pad, K_i)
-    delay_intra = d_.reshape(A, n_pad, K_i)
-    s_, w_, d_ = _inter_rows(spec, seed, rows, n_pad, sizes)
-    src_inter = s_.reshape(A, n_pad, K_e)
-    w_inter = w_.reshape(A, n_pad, K_e)
-    delay_inter = d_.reshape(A, n_pad, K_e)
+    # Each phase carries a profiler span: the draws of the incoming tables
+    # (``repro.build.draw``) and their inversion into outgoing tables, with
+    # padding and upload (``repro.build.invert``).
+    with TraceAnnotation("repro.build.draw"):
+        s_, w_, d_ = _intra_rows(spec, seed, rows, n_pad, sizes)
+        src_intra = s_.reshape(A, n_pad, K_i)
+        w_intra = w_.reshape(A, n_pad, K_i)
+        delay_intra = d_.reshape(A, n_pad, K_i)
+        s_, w_, d_ = _inter_rows(spec, seed, rows, n_pad, sizes)
+        src_inter = s_.reshape(A, n_pad, K_e)
+        w_inter = w_.reshape(A, n_pad, K_e)
+        delay_inter = d_.reshape(A, n_pad, K_e)
 
     out: dict = {}
     if outgoing:
-        # Invert the incoming tables per tier (paper's short/long split).
-        ti, wi, di = [], [], []
-        for a in range(A):
-            t_, w_, d_ = _invert_adjacency(
-                src_intra[a], w_intra[a], delay_intra[a], n_pad)
-            ti.append(t_), wi.append(w_), di.append(d_)
-        k_i = max(t.shape[1] for t in ti)
+        with TraceAnnotation("repro.build.invert"):
+            # Invert the incoming tables per tier (paper's short/long split).
+            ti, wi, di = [], [], []
+            for a in range(A):
+                t_, w_, d_ = _invert_adjacency(
+                    src_intra[a], w_intra[a], delay_intra[a], n_pad)
+                ti.append(t_), wi.append(w_), di.append(d_)
+            k_i = max(t.shape[1] for t in ti)
 
-        def padk(x, k, fill):
-            return np.pad(x, ((0, 0), (0, k - x.shape[1])),
-                          constant_values=fill)
+            def padk(x, k, fill):
+                return np.pad(x, ((0, 0), (0, k - x.shape[1])),
+                              constant_values=fill)
 
-        out["tgt_intra"] = jnp.asarray(
-            np.stack([padk(t, k_i, -1) for t in ti]))
-        out["wout_intra"] = jnp.asarray(
-            np.stack([padk(w, k_i, 0.0) for w in wi]))
-        out["dout_intra"] = jnp.asarray(
-            np.stack([padk(d, k_i, 1) for d in di]))
-        if K_e > 0 and outgoing != "intra":
-            # Global id space for both sources and targets.
-            t_, w_, d_ = _invert_adjacency(
-                src_inter.reshape(A * n_pad, K_e),
-                w_inter.reshape(A * n_pad, K_e),
-                delay_inter.reshape(A * n_pad, K_e),
-                A * n_pad,
-            )
-            out["tgt_inter"] = jnp.asarray(t_.reshape(A, n_pad, -1))
-            out["wout_inter"] = jnp.asarray(w_.reshape(A, n_pad, -1))
-            out["dout_inter"] = jnp.asarray(d_.reshape(A, n_pad, -1))
+            out["tgt_intra"] = jnp.asarray(
+                np.stack([padk(t, k_i, -1) for t in ti]))
+            out["wout_intra"] = jnp.asarray(
+                np.stack([padk(w, k_i, 0.0) for w in wi]))
+            out["dout_intra"] = jnp.asarray(
+                np.stack([padk(d, k_i, 1) for d in di]))
+            if K_e > 0 and outgoing != "intra":
+                # Global id space for both sources and targets.
+                t_, w_, d_ = _invert_adjacency(
+                    src_inter.reshape(A * n_pad, K_e),
+                    w_inter.reshape(A * n_pad, K_e),
+                    delay_inter.reshape(A * n_pad, K_e),
+                    A * n_pad,
+                )
+                out["tgt_inter"] = jnp.asarray(t_.reshape(A, n_pad, -1))
+                out["wout_inter"] = jnp.asarray(w_.reshape(A, n_pad, -1))
+                out["dout_inter"] = jnp.asarray(d_.reshape(A, n_pad, -1))
 
     # Delay-window metadata for delay-resolved delivery: the tightest
     # [lo, lo + span) covering the actual draws of each pathway table.

@@ -439,14 +439,14 @@ def resolve_params(net: Network, spec: MultiAreaSpec, cfg: EngineConfig):
     reference scales ``spec.ext_rate_hz`` (Fig. 8b heterogeneity), in the
     exact expression the shared update closure uses
     (:func:`repro.core.schedule.make_update_fn`), so the fused superstep
-    kernel and the phase profiler time/drive the same math bit-for-bit.
+    kernel drives the same math bit-for-bit.
     """
     lif_params = cfg.lif
     if abs(lif_params.dt_ms - net.dt_ms) > 1e-12:
         lif_params = dataclasses.replace(lif_params, dt_ms=net.dt_ms)
     # ShapeDtypeStruct stand-ins (dry-run lowering) carry no data to scale;
-    # the eager drive_rate is only consumed by the single-host fused kernel
-    # and the phase profiler, which always hold real networks.
+    # the eager drive_rate is only consumed by the single-host fused kernel,
+    # which always holds a real network.
     drive_rate = (
         net.rate_hz * (spec.ext_rate_hz / 2.5)
         if hasattr(net.rate_hz, "__array__") else None

@@ -69,9 +69,10 @@ Trajectories are bit-identical to the static path whenever the static path
 itself drops nothing (same compaction order, padding scatters +0.0).
 
 Wire-byte accounting: every exchange reports ``wire_bytes(net)`` -- static
-mesh-total bytes received per window, split by pathway -- feeding
-``launch/simulate.py --profile``, ``benchmarks/bench_delivery.py`` and the
-:mod:`repro.core.cost_model` communication term. :func:`wire_report`
+mesh-total bytes received per window, split by pathway -- feeding the
+wire table ``launch/simulate.py --profile`` prints beside its trace,
+``benchmarks/bench_delivery.py`` and the :mod:`repro.core.cost_model`
+communication term. :func:`wire_report`
 computes the dense-vs-routed comparison for a hypothetical mesh shape
 without constructing devices; each entry now carries **both** the static
 worst case and the adaptive two-phase model (phase-1 count bytes +
@@ -91,7 +92,7 @@ import numpy as np
 from repro.core import comm
 from repro.core import delivery as delivery_lib
 from repro.core.connectivity import Network
-from repro.core.schedule import CONVENTIONAL, STRUCTURE_AWARE
+from repro.core.schedule import CONVENTIONAL, INTER_EXCHANGE, STRUCTURE_AWARE
 from repro.kernels import ops as kops
 
 __all__ = [
@@ -357,19 +358,21 @@ class LocalExchange(Exchange):
                     r, sf, net, t, backend=self.backend, s_max=b),
                 ring)
             if inter_now:
-                ring = kops.ladder_switch(
-                    self.ladder_all, per_area.sum(),
-                    lambda b, r: delivery_lib.deliver_inter(
-                        r, sf.reshape(-1), net, t,
-                        backend=self.backend, s_max=b),
-                    ring)
+                with jax.named_scope(INTER_EXCHANGE):
+                    ring = kops.ladder_switch(
+                        self.ladder_all, per_area.sum(),
+                        lambda b, r: delivery_lib.deliver_inter(
+                            r, sf.reshape(-1), net, t,
+                            backend=self.backend, s_max=b),
+                        ring)
             return ring, jnp.int32(0), jnp.float32(0)
         ring = delivery_lib.deliver_intra(
             ring, sf, net, t, backend=self.backend, s_max=self.s_max_area)
         if inter_now:
-            ring = delivery_lib.deliver_inter(
-                ring, sf.reshape(-1), net, t,
-                backend=self.backend, s_max=self.s_max_all)
+            with jax.named_scope(INTER_EXCHANGE):
+                ring = delivery_lib.deliver_inter(
+                    ring, sf.reshape(-1), net, t,
+                    backend=self.backend, s_max=self.s_max_all)
         return ring, self._overflow(spikes, net, inter_now), jnp.float32(0)
 
     def window_end(self, ring, block, t0, net, gids, *, blocked: bool):
@@ -693,7 +696,12 @@ class DenseMeshExchange(Exchange):
         return ring, over, shipped
 
     def _cycle_conventional(self, ring, spikes, t, net, gids):
-        """One mesh-wide exchange feeds both pathways (round-robin layout)."""
+        """One mesh-wide exchange feeds both pathways (round-robin layout).
+
+        The exchange and the long-range deposit run under the
+        ``inter_exchange`` scope; the short-range deposit stays in the
+        caller's ``intra_deliver``.
+        """
         A, n_pad = net.n_areas, net.n_pad
         n_loc = spikes.shape[-1]
         r_len = ring.shape[-1]
@@ -712,10 +720,12 @@ class DenseMeshExchange(Exchange):
                 return jnp.where(keep, il, -1)
 
             def exchange_cycle(s_max, ring):
-                packet, count = delivery_lib.compact_fired(
-                    spikes, gids, s_max=s_max, invalid=A * n_pad)
-                wire = jax.lax.all_gather(
-                    packet, self.all_axes, axis=0, tiled=True)  # [n_dev*s]
+                with jax.named_scope(INTER_EXCHANGE):
+                    packet, count = delivery_lib.compact_fired(
+                        spikes, gids, s_max=s_max, invalid=A * n_pad)
+                    wire = jax.lax.all_gather(
+                        packet, self.all_axes, axis=0,
+                        tiled=True)                          # [n_dev*s]
                 if net.k_intra > 0:
                     # Short-range: per-area within-area ids from the list.
                     areas = jnp.arange(A, dtype=jnp.int32)
@@ -735,17 +745,20 @@ class DenseMeshExchange(Exchange):
                         keep = (il >= 0) & (il < n_loc)
                         return jnp.where(keep, (g // n_pad) * n_loc + il, -1)
 
-                    ring = kops.event_deliver_ids(
-                        ring.reshape(A * n_loc, r_len), wire, tgt_f, w_f,
-                        d_f, t, tgt_map=glob_local).reshape(A, n_loc, r_len)
+                    with jax.named_scope(INTER_EXCHANGE):
+                        ring = kops.event_deliver_ids(
+                            ring.reshape(A * n_loc, r_len), wire, tgt_f,
+                            w_f, d_f, t, tgt_map=glob_local,
+                        ).reshape(A, n_loc, r_len)
                 return ring, count
 
             if self.adaptive:
                 # Phase 1: mesh-max fired count this cycle; phase 2: one
                 # rung-sized packet per device. Top rung = the device's
                 # whole shard (A * n_loc), so no count can exceed it.
-                need = comm.count_max(
-                    spikes.sum(dtype=jnp.int32), self.all_axes)
+                with jax.named_scope(INTER_EXCHANGE):
+                    need = comm.count_max(
+                        spikes.sum(dtype=jnp.int32), self.all_axes)
                 ring, _ = kops.ladder_switch(
                     self.ladder_dev, need, exchange_cycle, ring)
                 rung = kops.ladder_rung(self.ladder_dev, need)
@@ -755,17 +768,21 @@ class DenseMeshExchange(Exchange):
                     + comm.count_wire_bytes(1, self.n_dev))
             else:
                 ring, count = exchange_cycle(self.s_max_dev, ring)
-                over = jax.lax.psum(
-                    jnp.maximum(count - self.s_max_dev, 0), self.all_axes)
+                with jax.named_scope(INTER_EXCHANGE):
+                    over = jax.lax.psum(
+                        jnp.maximum(count - self.s_max_dev, 0),
+                        self.all_axes)
         else:
             # One global all_gather per cycle: every device needs the full
             # vector because its neurons' sources are scattered everywhere.
-            full = comm.gather_full(s8, self.all_axes)
-            full_f = full.astype(jnp.float32)  # [A, n_pad]
+            with jax.named_scope(INTER_EXCHANGE):
+                full = comm.gather_full(s8, self.all_axes)
+                full_f = full.astype(jnp.float32)  # [A, n_pad]
             ring = delivery_lib.deliver_intra(
                 ring, full_f, net, t, backend=self.backend)
-            ring = delivery_lib.deliver_inter(
-                ring, full_f.reshape(-1), net, t, backend=self.backend)
+            with jax.named_scope(INTER_EXCHANGE):
+                ring = delivery_lib.deliver_inter(
+                    ring, full_f.reshape(-1), net, t, backend=self.backend)
         return ring, over, shipped
 
     def window_end(self, ring, block, t0, net, gids, *, blocked: bool):

@@ -38,6 +38,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import faults as faults_lib
 from repro.core import neuron as neuron_lib
@@ -47,6 +48,7 @@ from repro.core import ring_buffer
 __all__ = [
     "CONVENTIONAL",
     "STRUCTURE_AWARE",
+    "SCOPES",
     "SimState",
     "SimCheckpointer",
     "RunResult",
@@ -61,6 +63,14 @@ __all__ = [
 
 CONVENTIONAL = "conventional"
 STRUCTURE_AWARE = "structure_aware"
+
+# The window program's named scopes (``jax.named_scope``): every op of a
+# window lies under one of them but the loops' plumbing and the stacking of
+# the window's spike raster, so a profiler trace groups the device time by
+# layer. An op belongs to the innermost of these names in its ``op_name``.
+# Scopes are compile-time metadata; the compiled program is unchanged.
+SCOPES = ("neuron_update", "intra_deliver", "inter_exchange", "ring")
+NEURON_UPDATE, INTRA_DELIVER, INTER_EXCHANGE, RING = SCOPES
 
 
 @jax.tree_util.register_dataclass
@@ -114,6 +124,9 @@ def bind_network(fn: Callable, net, gids, *, static_argnums=()) -> Callable:
     def bound(*args):
         return jitted(*args, net, gids)
 
+    # The program as compiled, for reading its HLO and scopes:
+    # ``bound.lower(*args).compile().as_text()``.
+    bound.lower = lambda *args: jitted.lower(*args, net, gids)
     return bound
 
 
@@ -143,6 +156,7 @@ def make_update_fn(
     """
     drive_scale = spec.ext_rate_hz / 2.5
 
+    @jax.named_scope(NEURON_UPDATE)
     def update(neuron_state, i_in, t, net, gids, seed=None, stim=None):
         if cfg.neuron_model == "lif":
             rate = net.rate_hz * drive_scale
@@ -205,8 +219,9 @@ def make_window_fn(
         # one pass. Every inter-area delay is >= D, so slot (t0+s+d) is
         # strictly in the future of the window -- causal (paper §2.1)
         # and bit-identical to D per-cycle deliveries.
-        ring, d_over, d_ship = exchange.window_end(
-            state.ring, block, t0, net, gids, blocked=blocked)
+        with jax.named_scope(INTER_EXCHANGE):
+            ring, d_over, d_ship = exchange.window_end(
+                state.ring, block, t0, net, gids, blocked=blocked)
         return dataclasses.replace(
             state, ring=ring, overflow=state.overflow + d_over,
             shipped_bytes=state.shipped_bytes + d_ship), block
@@ -229,19 +244,25 @@ def _make_compute_window(cfg, exchange, update_fn, fused_superstep):
 
         def cycle_state(st: SimState, inter_now: bool):
             """One deliver -> update -> collocate cycle on full SimState."""
-            i_in, ring = ring_buffer.read_and_clear(st.ring, st.t)
+            with jax.named_scope(RING):
+                i_in, ring = ring_buffer.read_and_clear(st.ring, st.t)
             nstate, spikes = update_fn(
                 st.neuron, i_in, st.t, net, gids, seed=st.seed, stim=st.stim)
-            ring, over, shipped = exchange.cycle(
-                ring, spikes, st.t, net, gids, inter_now=inter_now)
+            with jax.named_scope(INTRA_DELIVER):
+                ring, over, shipped = exchange.cycle(
+                    ring, spikes, st.t, net, gids, inter_now=inter_now)
+                over = st.overflow + over
+                shipped = st.shipped_bytes + shipped
+            with jax.named_scope(NEURON_UPDATE):
+                spike_count = st.spike_count + spikes.astype(jnp.int32)
             return dataclasses.replace(
                 st,
                 neuron=nstate,
                 ring=ring,
                 t=st.t + 1,
-                spike_count=st.spike_count + spikes.astype(jnp.int32),
-                overflow=st.overflow + over,
-                shipped_bytes=st.shipped_bytes + shipped,
+                spike_count=spike_count,
+                overflow=over,
+                shipped_bytes=shipped,
             ), spikes
 
         if cfg.schedule == CONVENTIONAL:
@@ -257,46 +278,50 @@ def _make_compute_window(cfg, exchange, update_fn, fused_superstep):
             # ring_len ≡ 0 mod D), read and cleared once; cycles consume
             # window-static columns of the live buffer ``fut``.
             W = net.live_window
-            fut, ring = ring_buffer.open_window(state.ring, t0, D, W)
-            neuron, over = state.neuron, state.overflow
-            shipped = state.shipped_bytes
+            with jax.named_scope(RING):
+                fut, ring = ring_buffer.open_window(state.ring, t0, D, W)
+            carry = (state.neuron, fut, state.overflow, state.shipped_bytes)
+
+            def step(carry, s):
+                """One cycle of the superstep on the live window ``fut``."""
+                neuron, fut, over, shipped = carry
+                with jax.named_scope(RING):
+                    i_in = fut[..., s]
+                neuron, spikes = update_fn(
+                    neuron, i_in, t0 + s, net, gids,
+                    seed=state.seed, stim=state.stim)
+                with jax.named_scope(INTRA_DELIVER):
+                    fut, d_over, d_ship = exchange.cycle(
+                        fut, spikes, s, net, gids, inter_now=False)
+                    over, shipped = over + d_over, shipped + d_ship
+                return (neuron, fut, over, shipped), spikes
+
             if fused_superstep is not None:
-                neuron, block, fut = fused_superstep(neuron, fut, t0)
+                neuron, block, fut = fused_superstep(state.neuron, fut, t0)
+                over, shipped = state.overflow, state.shipped_bytes
             elif cfg.superstep_unroll:
                 cols = []
                 for s in range(D):  # unrolled: s static, slot math vanishes
-                    neuron, spikes = update_fn(
-                        neuron, fut[..., s], t0 + s, net, gids,
-                        seed=state.seed, stim=state.stim)
-                    fut, d_over, d_ship = exchange.cycle(
-                        fut, spikes, s, net, gids, inter_now=False)
-                    over = over + d_over
-                    shipped = shipped + d_ship
+                    carry, spikes = step(carry, s)
                     cols.append(spikes)
-                block = jnp.stack(cols)
+                (neuron, fut, over, shipped), block = carry, jnp.stack(cols)
             else:
                 # Scan over the live window: slot access touches only the
                 # small [.., W] buffer (wrap-free), never the ring.
-                def body(carry, s):
-                    neuron, fut, over, shipped = carry
-                    neuron, spikes = update_fn(
-                        neuron, fut[..., s], t0 + s, net, gids,
-                        seed=state.seed, stim=state.stim)
-                    fut, d_over, d_ship = exchange.cycle(
-                        fut, spikes, s, net, gids, inter_now=False)
-                    return (neuron, fut, over + d_over,
-                            shipped + d_ship), spikes
-
                 (neuron, fut, over, shipped), block = jax.lax.scan(
-                    body, (neuron, fut, over, shipped),
-                    jnp.arange(D, dtype=jnp.int32))
-            ring = ring_buffer.merge_window_tail(ring, fut[..., D:], t0 + D)
+                    step, carry, jnp.arange(D, dtype=jnp.int32))
+            with jax.named_scope(RING):
+                ring = ring_buffer.merge_window_tail(
+                    ring, fut[..., D:], t0 + D)
+            with jax.named_scope(NEURON_UPDATE):
+                spike_count = (state.spike_count
+                               + block.astype(jnp.int32).sum(0))
             return dataclasses.replace(
                 state,
                 neuron=neuron,
                 ring=ring,
                 t=t0 + D,
-                spike_count=state.spike_count + block.astype(jnp.int32).sum(0),
+                spike_count=spike_count,
                 overflow=over,
                 shipped_bytes=shipped,
             ), block
@@ -348,20 +373,23 @@ def make_overlap_window_fn(
     blocked = bool(cfg.use_superstep)
 
     def window_overlap(state: SimState, inflight, net, gids):
-        ring = exchange.finish_window_end(
-            state.ring, inflight, net, gids, blocked=blocked)
+        with jax.named_scope(INTER_EXCHANGE):
+            ring = exchange.finish_window_end(
+                state.ring, inflight, net, gids, blocked=blocked)
         state = dataclasses.replace(state, ring=ring)
         t0 = state.t
         state, block = compute_window(state, net, gids)
-        inflight, d_over, d_ship = exchange.start_window_end(
-            block, t0, net, gids, blocked=blocked)
+        with jax.named_scope(INTER_EXCHANGE):
+            inflight, d_over, d_ship = exchange.start_window_end(
+                block, t0, net, gids, blocked=blocked)
         return dataclasses.replace(
             state, overflow=state.overflow + d_over,
             shipped_bytes=state.shipped_bytes + d_ship), inflight, block
 
     def drain(state: SimState, inflight, net, gids):
-        ring = exchange.finish_window_end(
-            state.ring, inflight, net, gids, blocked=blocked)
+        with jax.named_scope(INTER_EXCHANGE):
+            ring = exchange.finish_window_end(
+                state.ring, inflight, net, gids, blocked=blocked)
         return dataclasses.replace(state, ring=ring)
 
     return window_overlap, drain
@@ -689,6 +717,12 @@ def run_windows(
     block is this window's own emissions). A multi-tenant batch slices each
     trial's rows out of the block and finalises a request the moment its
     own duration is reached, independent of the batch's longest trial.
+
+    The host work of each window carries a profiler span
+    (``jax.profiler.TraceAnnotation``, ~1 us when no trace runs):
+    ``repro.window.dispatch``, ``repro.window.wait``,
+    ``repro.window.spike_count`` and, when due, ``repro.window.checkpoint``.
+    The callbacks carry none; a caller names its own.
     """
     fault_arg = faults if faults is not None else getattr(
         engine.config, "faults", None)
@@ -740,12 +774,15 @@ def run_windows(
 
     for _ in range(n_windows):
         t0 = time.perf_counter()
-        if overlapped:
-            state, inflight, block = engine.window_overlap(state, inflight)
-            in_flight_dirty = True
-        else:
-            state, block = engine.window(state)
-        jax.block_until_ready(state.ring)
+        with TraceAnnotation("repro.window.dispatch"):
+            if overlapped:
+                state, inflight, block = engine.window_overlap(
+                    state, inflight)
+                in_flight_dirty = True
+            else:
+                state, block = engine.window(state)
+        with TraceAnnotation("repro.window.wait"):
+            jax.block_until_ready(state.ring)
         w_done += 1
         if injector is not None:
             comp = injector.window_jitter_s(w_done)
@@ -759,12 +796,14 @@ def run_windows(
             else:
                 slept += injector.inject(comp + comm)
         times.append(time.perf_counter() - t0)
-        spikes.append(int(np.asarray(jnp.sum(block.astype(jnp.int32)))))
+        with TraceAnnotation("repro.window.spike_count"):
+            spikes.append(int(np.asarray(jnp.sum(block.astype(jnp.int32)))))
         if on_block is not None:
             on_block(w_done, block)
         if checkpointer is not None and checkpointer.due(w_done):
-            drain_pipeline()
-            checkpointer.maybe_save(state, window=w_done)
+            with TraceAnnotation("repro.window.checkpoint"):
+                drain_pipeline()
+                checkpointer.maybe_save(state, window=w_done)
         if on_window is not None:
             on_window(w_done, state)
         stop = stop_requested is not None and stop_requested()

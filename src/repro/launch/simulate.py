@@ -20,14 +20,12 @@ the adaptive and static paths are bit-identical.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import importlib.util
 import os
 import signal
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.compile_cache import enable_compile_cache
@@ -38,6 +36,9 @@ from repro.core.factory import make_simulation
 from repro.core import exchange as exchange_lib
 from repro.core import faults as faults_lib
 from repro.core import schedule as schedule_lib
+
+# Where ``--profile`` writes its trace (relative to the working directory).
+PROFILE_DIR = "simulate_profile"
 
 # XLA flags that let the overlapped exchange actually run concurrently on
 # GPU: collectives issued on their own async stream and the latency-hiding
@@ -130,130 +131,6 @@ class StopFlag:
         signal.signal(signal.SIGTERM, handler)
         signal.signal(signal.SIGINT, handler)
         return self
-
-
-def _time_loop(fn, *args, repeats: int = 3):
-    """Best wall time of a jitted callable (compiles on the first call)."""
-    jax.block_until_ready(fn(*args))
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def profile_phases(net, spec, cfg: EngineConfig, cycles: int = 200) -> None:
-    """Per-phase timing table: where a cycle's wall time actually goes.
-
-    Times each phase of the deliver -> update -> collocate cycle in
-    isolation (a jitted scan of `cycles` iterations per phase), so perf PRs
-    can attribute wins without ad-hoc instrumentation: ring read/clear
-    (per-cycle and blocked), neuron update, intra delivery, and inter
-    delivery (per-cycle and the superstep's single-pass block).
-    """
-    from repro.core import delivery, neuron as neuron_lib, ring_buffer
-    from repro.core.engine import resolve_params
-
-    backend = cfg.backend
-    A, n_pad = net.alive.shape
-    D = net.delay_ratio
-    # The engines' own param/drive derivation -- the profiler must time the
-    # same math Engine.run executes.
-    lif_params, drive_rate = resolve_params(net, spec, cfg)
-    eng = make_simulation(spec, cfg, net=net)
-    st = eng.init()
-    st, blk = eng.window(st)  # warmed-up state + a real spike raster
-    ring0 = st.ring
-    sf = blk[int(np.argsort(np.asarray(blk).reshape(D, -1).sum(1))[D // 2])
-             ].astype(jnp.float32)
-    block_f = blk.astype(jnp.float32).reshape(D, -1)
-    s_max_area, s_max_all = delivery.event_bounds(
-        net, headroom=cfg.s_max_headroom, floor=cfg.s_max_floor)
-    ts = jnp.arange(cycles, dtype=jnp.int32)
-
-    @jax.jit
-    def ph_read(ring):
-        def body(r, t):
-            i_in, r = ring_buffer.read_and_clear(r, t)
-            return r, i_in.sum()
-        return jax.lax.scan(body, ring, ts)
-
-    @jax.jit
-    def ph_read_block(ring):
-        def body(r, w):
-            blk_, r = ring_buffer.read_and_clear_block(r, w * D, D)
-            return r, blk_.sum()
-        return jax.lax.scan(body, ring, jnp.arange(cycles // D, dtype=jnp.int32))
-
-    @jax.jit
-    def ph_update(nstate):
-        def body(ns, t):
-            if cfg.neuron_model == "lif":
-                gids = jnp.arange(A * n_pad, dtype=jnp.int32).reshape(A, n_pad)
-                drive = neuron_lib.poisson_drive(
-                    cfg.seed, t, gids, drive_rate, net.dt_ms, spec.w_ext)
-                ns, spk = neuron_lib.lif_update(
-                    ns, drive, net.alive, lif_params)
-            else:
-                ns, spk = neuron_lib.ignore_and_fire_update(
-                    ns, None, net.alive, net.rate_hz, net.dt_ms)
-            return ns, spk.sum()
-        return jax.lax.scan(body, nstate, ts)
-
-    @jax.jit
-    def ph_intra(ring):
-        def body(r, t):
-            return delivery.deliver_intra(
-                r, sf, net, t, backend=backend, s_max=s_max_area), None
-        return jax.lax.scan(body, ring, ts)
-
-    @jax.jit
-    def ph_inter(ring):
-        def body(r, t):
-            return delivery.deliver_inter(
-                r, sf.reshape(-1), net, t, backend=backend,
-                s_max=s_max_all), None
-        return jax.lax.scan(body, ring, ts)
-
-    @jax.jit
-    def ph_inter_block(ring):
-        def body(r, w):
-            return delivery.deliver_inter_block(
-                r, block_f, net, w * D, backend=backend,
-                s_max=s_max_all), None
-        return jax.lax.scan(body, ring, jnp.arange(cycles // D, dtype=jnp.int32))
-
-    rows = [
-        ("ring read/clear (per-cycle)", _time_loop(ph_read, ring0)),
-        ("ring read/clear (blocked)", _time_loop(ph_read_block, ring0)),
-        ("neuron update (+drive)", _time_loop(ph_update, st.neuron)),
-        ("intra deliver", _time_loop(ph_intra, ring0)),
-        ("inter deliver (per-cycle)", _time_loop(ph_inter, ring0)),
-        ("inter deliver (blocked)", _time_loop(ph_inter_block, ring0)),
-    ]
-    print(f"\n-- phase profile: backend={backend}, {cycles} cycles each --")
-    print(f"{'phase':30s} {'us/cycle':>10s} {'cycles/s':>12s}")
-    for name, wall in rows:
-        print(f"{name:30s} {wall / cycles * 1e6:10.2f} {cycles / wall:12.1f}")
-    win = _time_loop(eng.window, st)
-    print(f"{'full window / D':30s} {win / D * 1e6:10.2f} {D / win:12.1f}")
-    if cfg.schedule == schedule_lib.STRUCTURE_AWARE:
-        # Sequential vs the double-buffered pipeline over the same windows:
-        # the pipelined run finishes window w's exchange while computing
-        # w+1, so the gap is the per-window comm wall the overlap absorbs
-        # (bit-identical trajectory either way).
-        eng_o = make_simulation(spec, dataclasses.replace(cfg, overlap_exchange=True), net=net)
-        k = max(cycles // D, 1)
-        seq = _time_loop(lambda s: eng.run(s, k), st)
-        pipe = _time_loop(lambda s: eng_o.run(s, k), st)
-        print(f"{f'window seq (run x{k})':30s} "
-              f"{seq / (k * D) * 1e6:10.2f} {k * D / seq:12.1f}")
-        print(f"{'window overlapped (pipeline)':30s} "
-              f"{pipe / (k * D) * 1e6:10.2f} {k * D / pipe:12.1f}")
-        print(f"  overlap hides {(seq - pipe) / k * 1e6:+.2f} us/window "
-              f"({(seq - pipe) / seq * 100:+.1f}% of sequential wall) "
-              f"on this host")
 
 
 def print_wire_volume(net, spec, cfg: EngineConfig, n_groups: int, gsz: int):
@@ -499,9 +376,13 @@ def main() -> None:
                          "run through the fault harness and the pipelined "
                          "injected wall must beat the sequential one")
     ap.add_argument("--profile", action="store_true",
-                    help="report per-phase timings (ring read/clear, update, "
-                         "intra/inter deliver) and the dense-vs-routed wire "
-                         "volume before the run")
+                    help=f"run each leg's timed windows through the "
+                         f"windowed run loop under jax.profiler.trace into "
+                         f"{PROFILE_DIR}/ (open it in TensorBoard or "
+                         f"Perfetto: device ops group by the window's named "
+                         f"scopes {', '.join(schedule_lib.SCOPES)}, host "
+                         f"work by the repro.window.* spans), and print "
+                         f"the dense-vs-routed wire volume before the run")
     ap.add_argument("--checkpoint-dir", default=None,
                     help="window-boundary SimState checkpoints through "
                          "checkpoint.AsyncWriter land here; enables the "
@@ -547,6 +428,10 @@ def main() -> None:
             "jitter-only --inject-fault spec)")
     if args.resume and not args.checkpoint_dir:
         raise SystemExit("--resume needs --checkpoint-dir")
+    if args.profile and (resilient or inject_compare):
+        raise SystemExit(
+            "--profile traces the plain windowed run; it cannot be combined "
+            "with --checkpoint-dir/--resume/--inject-fault")
     compare_fault_cfg = None
     if inject_compare:
         if args.compare or args.compare_adaptive:
@@ -605,7 +490,8 @@ def main() -> None:
 
     # The host-built global network: skipped entirely when every leg builds
     # sharded (the whole point -- its host RSS is the construction wall).
-    # The conventional --compare legs and the profiler still need it.
+    # The conventional --compare legs and --profile's wire table still
+    # need it.
     runs_conventional = args.compare or args.schedule == "conventional"
     needs_host_net = ((not args.sharded_build) or runs_conventional
                       or args.profile)
@@ -631,7 +517,6 @@ def main() -> None:
         neuron_model=neuron, schedule=args.schedule,
         delivery_backend=backend, seed=42)
     if args.profile:
-        profile_phases(net, spec, base_cfg)
         n_groups, gsz = (
             (mesh.shape["data"], mesh.shape["model"]) if mesh is not None
             else _pick_mesh(8, net.n_areas, net.n_pad) or (1, 8))
@@ -695,6 +580,19 @@ def main() -> None:
                 wall = float(res.window_times_s.sum())
                 windows_run = res.windows_done
                 injected[(sched, adaptive, overlap_on)] = res.injected_sleep_s
+            elif args.profile:
+                # The real run, traced: one dispatch per window, so the
+                # trace shows the window program and the host work between.
+                st = eng.init()
+                st, _ = eng.window(st)  # compile
+                jax.block_until_ready(st.ring)
+                t0 = time.perf_counter()
+                with jax.profiler.trace(PROFILE_DIR):
+                    res = schedule_lib.run_windows(eng, st, n_windows - 1)
+                wall = time.perf_counter() - t0
+                st = res.state
+                windows_run = res.windows_done
+                print(f"  profiler trace -> {os.path.abspath(PROFILE_DIR)}")
             else:
                 st = eng.init()
                 st, _ = eng.window(st)  # compile
