@@ -195,10 +195,12 @@ def deliver_intra(
     if backend == "event":
         # Single-host layout only (ring covers the full area); the sharded
         # event path compacts before the exchange -- see the engines.
-        return jax.vmap(
-            lambda rg, sp, tg, w, d: kops.event_deliver(
-                rg, sp > 0, tg, w, d, t, s_max=s_max)
-        )(ring, area_spikes, net.tgt_intra, net.wout_intra, net.dout_intra)
+        n_src = net.tgt_intra.shape[1]
+        fired = jax.vmap(
+            lambda sp: kops.sized_nonzero(sp > 0, size=s_max, fill=n_src)
+        )(area_spikes)
+        return kops.event_deliver_per_area(
+            ring, fired, net.tgt_intra, net.wout_intra, net.dout_intra, t)
     if backend == "pallas":
         k = net.src_intra.shape[-1]
         n_src = area_spikes.shape[-1]

@@ -658,10 +658,9 @@ class DenseMeshExchange(Exchange):
                 )(spikes)
                 wire = jax.lax.all_gather(
                     packets, self.subgroup, axis=1, tiled=True)
-                ring = jax.vmap(
-                    lambda r, idl, tg, w, d: kops.event_deliver_ids(
-                        r, idl, tg, w, d, t, tgt_map=to_local)
-                )(ring, wire, *self._intra_tables(net))
+                ring = kops.event_deliver_per_area(
+                    ring, wire, *self._intra_tables(net), t,
+                    tgt_map=to_local)
                 return ring, counts
 
             if self.adaptive:
@@ -732,10 +731,9 @@ class DenseMeshExchange(Exchange):
                     ids_a = jnp.where(
                         wire[None, :] // n_pad == areas[:, None],
                         wire[None, :] % n_pad, n_pad)       # [A, S]
-                    ring = jax.vmap(
-                        lambda r, idl, tg, w, d: kops.event_deliver_ids(
-                            r, idl, tg, w, d, t, tgt_map=win_local)
-                    )(ring, ids_a, *self._intra_tables(net))
+                    ring = kops.event_deliver_per_area(
+                        ring, ids_a, *self._intra_tables(net), t,
+                        tgt_map=win_local)
                 # Long-range: global target id -> (area row, local window).
                 if net.k_inter > 0:
                     tgt_f, w_f, d_f = self._inter_tables(net)

@@ -34,9 +34,22 @@ __all__ = [
     "event_deliver",
     "event_deliver_ids",
     "event_deliver_block",
+    "event_deliver_per_area",
+    "deposit_chunk",
+    "deposit_trips",
+    "DEPOSIT_CHUNK_SCOPE",
     "superstep_lif",
     "superstep_iaf",
 ]
+
+
+# Entries (packet slots x outgoing width) one chunk of the event deposit
+# gathers and scatters: 16 slots of a K_out ~ 3,300 table. A chunk pays one
+# loop iteration; a live prefix shorter than a chunk pays for the rest of it.
+DEPOSIT_CHUNK_ENTRIES = 1 << 16
+# Named scope of one chunk of the event deposit in a trace (not one of the
+# window's layer scopes: its ops stay charged to the caller's layer).
+DEPOSIT_CHUNK_SCOPE = "event_deposit_chunk"
 
 
 def default_interpret() -> bool:
@@ -223,9 +236,10 @@ def event_deliver_ids(
 
     This is the receive side of the sparse-id wire format (NEST sends spike
     *id packets*, not dense vectors): ``ids`` may come straight off an
-    ``all_gather`` of per-device compactions. Work is O(S * K_out): the
-    scatter goes *directly* into ``ring`` -- no intermediate full-ring buffer,
-    so the cost is bounded by the packet, not by N_tgt. Entries with
+    ``all_gather`` of per-device compactions. Work is O(K_out) per packet
+    slot up to the last live id, in chunks (:func:`event_deliver_block`):
+    the scatter goes *directly* into ``ring`` -- no intermediate full-ring
+    buffer, so the cost is bounded by the packet, not by N_tgt. Entries with
     ``ids[s] >= N_src`` (the compaction fill value) and table padding rows
     (``tgt = -1``, ``w = 0``) are neutralised by clamping their target into
     row 0 with value 0.0 -- adding +0.0 is bit-exact (ring slots are never
@@ -244,27 +258,79 @@ def event_deliver_ids(
         ring, ids.reshape(1, -1), tgt_out, w_out, d_out, t, tgt_map=tgt_map)
 
 
-def event_deliver_block(
-    ring: jax.Array,      # [N_tgt, R]
-    ids: jax.Array,       # [D, S] int32 fired ids per window cycle; >= N_src pads
-    tgt_out: jax.Array,   # [N_src, K_out] int32 target ids (N_tgt = no target)
-    w_out: jax.Array,     # [N_src, K_out] f32
-    d_out: jax.Array,     # [N_src, K_out] int8/int32 delays (steps)
-    t0: jax.Array,
+def event_deliver_per_area(
+    ring: jax.Array,      # [A, N_tgt, R]
+    ids: jax.Array,       # [A, S] int32 fired ids per area; >= N_src pads
+    tgt_out: jax.Array,   # [A, N_src, K_out] int32
+    w_out: jax.Array,     # [A, N_src, K_out] f32
+    d_out: jax.Array,     # [A, N_src, K_out] int8/int32
+    t: jax.Array,
     *,
     tgt_map=None,
 ) -> jax.Array:
-    """Single-pass blocked receive: scatter a whole window's id packets.
+    """:func:`event_deliver_ids` of each area's packet into its own ring
+    with its own tables.
 
-    ``ids[s]`` is the fired-id packet of window cycle ``s`` (an ``(id, step)``
-    packet of bound ``D * S`` with the step implicit in the row); entry
-    ``(s, i)`` deposits its outgoing synapses at slots ``(t0 + s + d) % R``.
-    This fuses the window-end loop of D sequential per-cycle scatters into ONE
-    O(D * S * K_out) scatter -- one kernel, one pass over the outgoing tables,
-    no per-cycle loop bookkeeping. Semantics (padding absorption, ``tgt_map``
-    remapping, exactness) are those of :func:`event_deliver_ids`, which is
-    the ``D == 1`` special case.
+    One chunk loop serves every area: it runs to the last live id of any
+    area, and each trip deposits that chunk of every area's packet. The
+    trip count is one scalar, so the loop is not batched (a ``vmap`` of
+    :func:`event_deliver_ids` would select each area's ring in every trip).
     """
+    n_src, k_out = tgt_out.shape[1:]
+
+    def deposit(ring, part):
+        return jax.vmap(
+            lambda rg, p, tg, w, d: _deposit_packets(
+                rg, p[None], tg, w, d, t, tgt_map)
+        )(ring, part, tgt_out, w_out, d_out)
+
+    return _deposit_chunks(ring, ids, n_src, k_out, deposit)
+
+
+def deposit_chunk(k_out: int, s: int) -> int:
+    """Packet slots per chunk of the event deposit for packets of ``s``.
+
+    The largest power of two ``C`` with ``C * k_out <=
+    DEPOSIT_CHUNK_ENTRIES``, at least 8 and at most ``s``: the chunk width
+    follows the shapes alone, so every caller of one table set agrees.
+    """
+    per_slot = DEPOSIT_CHUNK_ENTRIES // max(k_out, 1)
+    return min(1 << max(3, per_slot.bit_length() - 1), max(s, 1))
+
+
+def deposit_trips(ids: jax.Array, n_src: int, k_out: int) -> jax.Array:
+    """Chunks the event deposit runs for the packets ``ids [..., S]``.
+
+    ``ceil(last / C)``, where ``last`` is the position after the last live
+    id (``< n_src``) of any row and ``C = deposit_chunk(k_out, S)``. The
+    last position, not the count: packets concatenated from several
+    devices carry fill between their live runs, which then costs only
+    padding.
+    """
+    s = ids.shape[-1]
+    pos = jnp.where(ids < n_src, jnp.arange(1, s + 1, dtype=jnp.int32), 0)
+    c = deposit_chunk(k_out, s)
+    return (pos.max() + (c - 1)) // c
+
+
+def _deposit_chunks(ring, ids, n_src, k_out, deposit):
+    """``deposit(ring, ids[..., chunk])`` over the column chunks of ``ids``
+    up to the chunk holding its last live id, under the chunk scope."""
+    c = deposit_chunk(k_out, ids.shape[-1])
+    trips = deposit_trips(ids, n_src, k_out)
+    ids = _pad_to(ids, c, axis=ids.ndim - 1, value=n_src)
+
+    def chunk(i, ring):
+        with jax.named_scope(DEPOSIT_CHUNK_SCOPE):
+            part = jax.lax.dynamic_slice_in_dim(ids, i * c, c, axis=-1)
+            return deposit(ring, part)
+
+    return jax.lax.fori_loop(0, trips, chunk, ring)
+
+
+def _deposit_packets(ring, ids, tgt_out, w_out, d_out, t0, tgt_map):
+    """Scatter every entry of the id packets ``ids [D, S]``; entry ``(s, i)``
+    deposits at slots ``(t0 + s + d) % R``."""
     n_tgt, r = ring.shape
     n_src, _ = tgt_out.shape
     d_win, s_max = ids.shape
@@ -282,6 +348,39 @@ def event_deliver_block(
     # int8-stored) table entries to int32 at the gather.
     slots = jnp.mod(t0 + steps[:, None] + d_out[safe].astype(jnp.int32), r)
     return ring.at[tgts.reshape(-1), slots.reshape(-1)].add(vals.reshape(-1))
+
+
+def event_deliver_block(
+    ring: jax.Array,      # [N_tgt, R]
+    ids: jax.Array,       # [D, S] int32 fired ids per window cycle; >= N_src pads
+    tgt_out: jax.Array,   # [N_src, K_out] int32 target ids (N_tgt = no target)
+    w_out: jax.Array,     # [N_src, K_out] f32
+    d_out: jax.Array,     # [N_src, K_out] int8/int32 delays (steps)
+    t0: jax.Array,
+    *,
+    tgt_map=None,
+) -> jax.Array:
+    """Single-pass blocked receive: scatter a whole window's id packets.
+
+    ``ids[s]`` is the fired-id packet of window cycle ``s`` (an ``(id, step)``
+    packet of bound ``D * S`` with the step implicit in the row); entry
+    ``(s, i)`` deposits its outgoing synapses at slots ``(t0 + s + d) % R``.
+    This fuses the window-end loop of D sequential per-cycle scatters into
+    one deposit with no per-cycle loop bookkeeping. Semantics
+    (padding absorption, ``tgt_map`` remapping, exactness) are those of
+    :func:`event_deliver_ids`, which is the ``D == 1`` special case.
+
+    The packets are compacted (live ids first), so the work follows the
+    spikes, not the bound: the deposit runs over column chunks of
+    ``C = deposit_chunk(K_out, S)`` slots, :func:`deposit_trips` of them,
+    and skips the rest, whose entries would only add +0.0.
+    """
+    n_src, k_out = tgt_out.shape
+
+    def deposit(ring, part):
+        return _deposit_packets(ring, part, tgt_out, w_out, d_out, t0, tgt_map)
+
+    return _deposit_chunks(ring, ids, n_src, k_out, deposit)
 
 
 def _pad_rows(xs: dict, tile: int, fills: dict):
@@ -359,10 +458,11 @@ def event_deliver(
 ) -> jax.Array:
     """Event-driven delivery: compact fired sources, scatter their targets.
 
-    Work is O(s_max * K_out) instead of O(N * K); with brain-scale rates this
-    is a >1000x multiply-reduction. ``s_max`` is the static event-buffer bound
-    (cf. NEST's spike-register resizing -- here sizing is static; the engine
-    surfaces an overflow counter in ``SimState`` when the bound is exceeded).
+    Work is O(fired * K_out), at most O(s_max * K_out), instead of
+    O(N * K); with brain-scale rates this is a >1000x multiply-reduction.
+    ``s_max`` is the static event-buffer bound (cf. NEST's spike-register
+    resizing -- here sizing is static; the engine surfaces an overflow
+    counter in ``SimState`` when the bound is exceeded).
     """
     n_src = tgt_out.shape[0]
     fired = sized_nonzero(spikes.reshape(-1), size=s_max, fill=n_src)

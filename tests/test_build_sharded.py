@@ -183,6 +183,58 @@ def test_outgoing_intra_skips_inter_inversion():
         build_network(spec, seed=12, outgoing="bogus")
 
 
+def test_build_network_threaded_tasks_bitwise(monkeypatch):
+    """The host build's threaded draws and inversion, cut into tasks of 64
+    synapses (about a hundred per pathway), give the tables of one task
+    per pathway, bitwise."""
+    import dataclasses
+
+    from repro.core import connectivity
+
+    spec = _spec()
+    whole = connectivity.build_network(spec, seed=12, size_multiple=8,
+                                       outgoing=True)
+    monkeypatch.setattr(connectivity, "_DRAW_TASK_SYNAPSES", 64)
+    tasks = connectivity.build_network(spec, seed=12, size_multiple=8,
+                                       outgoing=True)
+    for field in dataclasses.fields(whole):
+        a, b = getattr(whole, field.name), getattr(tasks, field.name)
+        if hasattr(a, "shape"):
+            assert a.dtype == b.dtype, field.name
+            assert np.array_equal(np.asarray(a), np.asarray(b)), field.name
+        else:
+            assert a == b, field.name
+
+
+def test_invert_adjacency_threaded_placement_stress(monkeypatch):
+    """Placement slices written from more threads than cores, with a short
+    switch interval, leave the tables the serial placement leaves: no
+    entry is lost or misplaced."""
+    import concurrent.futures
+
+    from repro.core import connectivity
+
+    rng = np.random.default_rng(3)
+    n_src, n_tgt, k = 300, 400, 50
+    src = rng.integers(0, n_src, (n_tgt, k)).astype(np.int32)
+    w = (rng.integers(-512, 512, (n_tgt, k)) / 256.0).astype(np.float32)
+    d = rng.integers(1, 9, (n_tgt, k)).astype(np.int8)
+    want = connectivity._invert_adjacency(src, w, d, n_src)
+    monkeypatch.setattr(connectivity, "_DRAW_TASK_SYNAPSES", 37)
+    workers = min((os.cpu_count() or 1) + 2, 64)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+            for _ in range(5):
+                got = connectivity._invert_adjacency(
+                    src, w, d, n_src, pool=pool)
+                for g, x in zip(got, want):
+                    assert g.dtype == x.dtype and np.array_equal(g, x)
+    finally:
+        sys.setswitchinterval(interval)
+
+
 def test_k_inter_zero_edge():
     """K_e == 0: the plan degenerates cleanly and the shard builder returns
     width-0 tables matching the host build's empty inter pathway."""

@@ -547,3 +547,58 @@ def test_network_sds_outgoing_mirrors_build():
     lowered = jax.jit(eng.window_raw).lower(state_sds, net_in, gids_sds)
     assert "ppermute" in lowered.as_text() or True  # lowering must succeed
     lowered.compile()
+
+
+def test_chunked_event_deposit_distributed_bitwise():
+    """The event deposit's chunk loop on the distributed exchanges, whose
+    packets have holes (tiled all_gathers of per-device packets, routed
+    rounds side by side, the conventional schedule's per-area split of one
+    wire): with outgoing tables ~4,400 wide, so 8-slot chunks, every path
+    fills the ring as the single-host scatter backend does, bitwise."""
+    print(_run("""
+        import numpy as np, jax
+        from repro.core.areas import mam_benchmark_spec
+        from repro.core.connectivity import build_network
+        from repro.core.engine import EngineConfig
+        from repro.core.factory import make_simulation
+        from repro.kernels import ops
+
+        spec = mam_benchmark_spec(n_areas=4, n_per_area=64, k_intra=4200,
+                                  k_inter=4200, rate_hz=2000.0)
+        net = build_network(spec, seed=5, size_multiple=8, outgoing=True)
+        assert ops.deposit_chunk(net.tgt_intra.shape[-1], 1 << 10) == 8
+        kw = dict(neuron_model="ignore_and_fire", s_max_floor=24)
+
+        def run(eng, n=4):
+            st, out = eng.init(), []
+            for _ in range(n):
+                st, b = eng.window(st)
+                out.append((np.asarray(b).astype(bool),
+                            np.asarray(st.ring)))
+            return st, out
+
+        ref_st, ref = run(make_simulation(
+            spec, EngineConfig(**kw, delivery_backend="scatter"), net=net))
+        assert sum(b.sum() for b, _ in ref) > 1500
+        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        for tag, extra in [
+                ("dense", dict(exchange="dense")),
+                ("dense-adaptive", dict(exchange="dense",
+                                        adaptive_exchange=True)),
+                ("routed", dict(exchange="routed")),
+                ("routed-adaptive", dict(exchange="routed",
+                                         adaptive_exchange=True)),
+                ("conventional", dict(exchange="dense",
+                                      schedule="conventional"))]:
+            eng = make_simulation(
+                spec, EngineConfig(**kw, delivery_backend="event", **extra),
+                net=net, mesh=mesh)
+            hlo = eng.window.lower(eng.init()).as_text(debug_info=True)
+            assert ops.DEPOSIT_CHUNK_SCOPE in hlo, tag
+            st, out = run(eng)
+            for w, ((b, ring), (rb, rring)) in enumerate(zip(out, ref)):
+                assert np.array_equal(b, rb), (tag, w)
+                assert np.array_equal(ring, rring), (tag, w)
+            assert int(st.overflow) == 0, tag
+            print("OK", tag)
+    """))

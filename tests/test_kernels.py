@@ -199,6 +199,149 @@ def test_event_deliver_block_matches_per_cycle_ids():
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
+def _one_shot_deposit(ring, ids, tgt, w, d, t0, tgt_map=None):
+    """The event deposit as one static scatter of every packet slot: the
+    oracle of the chunked deposit (fill and holes add +0.0 to row 0)."""
+    n_tgt, r = ring.shape
+    d_win, s_max = ids.shape
+    steps = jnp.repeat(jnp.arange(d_win, dtype=jnp.int32), s_max)
+    ids = ids.reshape(-1)
+    valid = ids < tgt.shape[0]
+    safe = jnp.where(valid, ids, 0)
+    tg = tgt[safe] if tgt_map is None else tgt_map(tgt[safe])
+    ok = valid[:, None] & (tg >= 0) & (tg < n_tgt)
+    vals = jnp.where(ok, w[safe], 0.0)
+    slots = jnp.mod(t0 + steps[:, None] + d[safe].astype(jnp.int32), r)
+    return ring.at[jnp.where(ok, tg, 0).reshape(-1),
+                   slots.reshape(-1)].add(vals.reshape(-1))
+
+
+# K_out wide enough that a chunk is the minimum of 8 slots; S = 21 is not a
+# multiple of it, so the last chunk runs over padding.
+CHUNK_K_OUT, CHUNK_N_SRC, CHUNK_S = 4100, 48, 21
+
+
+def _chunk_case(case, rng):
+    """``(ids [D, S], tgt_map, int8 delays, K_out)`` for one chunked-deposit
+    case."""
+    c, fill = ops.deposit_chunk(CHUNK_K_OUT, CHUNK_S), CHUNK_N_SRC
+    live = lambda k: rng.choice(CHUNK_N_SRC, k, replace=False)
+    ids = np.full((1, CHUNK_S), fill, np.int32)
+    tgt_map, int8, k_out = None, False, CHUNK_K_OUT
+    if case == "empty":
+        pass
+    elif case == "one":
+        ids[0, 0] = live(1)[0]
+    elif case == "chunk":
+        ids[0, :c] = live(c)
+    elif case == "chunk_plus_one":
+        ids[0, :c + 1] = live(c + 1)
+    elif case == "full":
+        ids[0] = live(CHUNK_S)
+    elif case == "rows":
+        ids = np.full((4, CHUNK_S), fill, np.int32)
+        for row, k in enumerate((0, 3, CHUNK_S, c + 2)):
+            ids[row, :k] = live(k)
+    elif case == "holes":
+        # Two per-device packets of 10 slots side by side, as a tiled
+        # all_gather lays them out: live runs with fill between them.
+        ids = np.full((2, 20), fill + 3, np.int32)
+        ids[0, :2], ids[0, 10:17] = live(2), live(7)
+        ids[1, 10:11] = live(1)
+    elif case == "tgt_map":
+        ids[0, :c + 3] = live(c + 3)
+        tgt_map = lambda g: jnp.where(g % 3 == 0, -1, g // 2)
+    elif case == "int8_delays":
+        ids[0, :2 * c] = live(2 * c)
+        int8 = True
+    elif case == "narrow_table":
+        # A table narrow enough that one chunk covers the packet: C = S.
+        ids[0, :CHUNK_S - 2] = live(CHUNK_S - 2)
+        k_out = 32
+    return ids, tgt_map, int8, k_out
+
+
+@pytest.mark.parametrize("case", [
+    "empty", "one", "chunk", "chunk_plus_one", "full", "rows", "holes",
+    "tgt_map", "int8_delays", "narrow_table"])
+def test_event_deliver_block_chunked_matches_one_shot(case):
+    """The chunked deposit, which stops after the chunk holding each
+    block's last live id, == one static scatter of every slot, bitwise; its
+    trip count is ceil(last live position / C)."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    assert ops.deposit_chunk(CHUNK_K_OUT, CHUNK_S) == 8
+    assert ops.deposit_chunk(3337, 1030) == 16
+    assert ops.deposit_chunk(32, CHUNK_S) == CHUNK_S
+    ids, tgt_map, int8, k_out = _chunk_case(case, rng)
+    c = ops.deposit_chunk(k_out, ids.shape[1])
+    n_tgt, r = 40, 12
+    shape = (CHUNK_N_SRC, k_out)
+    tgt = jnp.asarray(rng.integers(-1, n_tgt, shape), jnp.int32)
+    w = jnp.asarray(np.round(rng.normal(0, 64, shape)) / 256.0, jnp.float32)
+    d = jnp.asarray(rng.integers(1, r - 1, shape),
+                    jnp.int8 if int8 else jnp.int32)
+    ring = jnp.asarray(np.round(rng.normal(0, 512, (n_tgt, r))) / 256.0,
+                       jnp.float32)
+    t0 = jnp.int32(5)
+
+    live = ids < CHUNK_N_SRC
+    last = max((np.flatnonzero(row)[-1] + 1 if row.any() else 0)
+               for row in live)
+    trips = ops.deposit_trips(jnp.asarray(ids), CHUNK_N_SRC, k_out)
+    assert int(trips) == -(-last // c)
+
+    deposit = jax.jit(functools.partial(
+        ops.event_deliver_block, tgt_map=tgt_map))
+    got = deposit(ring, jnp.asarray(ids), tgt, w, d, t0)
+    want = jax.jit(functools.partial(_one_shot_deposit, tgt_map=tgt_map))(
+        ring, jnp.asarray(ids), tgt, w, d, t0)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    if case == "empty":
+        assert np.array_equal(np.asarray(got), np.asarray(ring))
+
+
+def _while_eqns(jaxpr):
+    """Every ``while`` equation of a jaxpr, nested ones included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            out.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_while_eqns(sub))
+    return out
+
+
+def test_event_deliver_per_area_matches_one_shot():
+    """Per-area deposits through one chunk loop, which runs to the last
+    live id of any area, == per-area one-shot scatters; the loop's
+    predicate is one scalar, not one flag per area."""
+    rng = np.random.default_rng(19)
+    a, n, r = 3, CHUNK_N_SRC, 10
+    shape = (a, n, CHUNK_K_OUT)
+    tgt = jnp.asarray(rng.integers(-1, n, shape), jnp.int32)
+    w = jnp.asarray(np.round(rng.normal(0, 64, shape)) / 256.0, jnp.float32)
+    d = jnp.asarray(rng.integers(1, r - 1, shape), jnp.int8)
+    ids = np.full((a, CHUNK_S), n, np.int32)
+    for row, k in enumerate((1, 0, 17)):
+        ids[row, :k] = rng.choice(n, k, replace=False)
+    ids = jnp.asarray(ids)
+    ring = jnp.zeros((a, n, r), jnp.float32)
+    t = jnp.int32(2)
+
+    def deliver(ring, ids):
+        return ops.event_deliver_per_area(ring, ids, tgt, w, d, t)
+
+    got = jax.jit(deliver)(ring, ids)
+    for k in range(a):
+        want = _one_shot_deposit(ring[k], ids[k][None], tgt[k], w[k], d[k], t)
+        assert np.array_equal(np.asarray(got[k]), np.asarray(want)), k
+    (loop,) = _while_eqns(jax.make_jaxpr(deliver)(ring, ids).jaxpr)
+    cond = loop.params["cond_jaxpr"].jaxpr
+    assert cond.outvars[0].aval.shape == (), cond
+    hlo = jax.jit(deliver).lower(ring, ids).as_text(debug_info=True)
+    assert ops.DEPOSIT_CHUNK_SCOPE in hlo
+
+
 def test_superstep_kernels_match_unfused_window():
     """kernels/cycle.py: one fused window (D cycles of update + intra
     delivery on a VMEM-resident live buffer) == the unfused op chain."""

@@ -395,3 +395,35 @@ def test_event_delivery_equals_dense_engine():
         assert np.array_equal(np.asarray(bd), np.asarray(be)), w
         assert np.array_equal(np.asarray(sd.ring), np.asarray(se.ring)), w
     assert int(sd.spike_count.sum()) > 100
+
+
+@pytest.mark.parametrize("kw", [
+    dict(), dict(superstep=False), dict(schedule="conventional"),
+    dict(adaptive_exchange=True),
+], ids=["superstep", "legacy", "conventional", "adaptive"])
+def test_chunked_event_deposit_engine_bitwise(kw):
+    """The event deposit's chunk loop fills the ring as the scatter backend
+    does, bitwise, on every single-host exchange path. Outgoing tables
+    ~4,400 wide make 8-slot chunks against packets of 126 (per area) and
+    505 (per cycle) slots, with ~13 and ~51 live ids."""
+    from repro.kernels import ops
+
+    spec = mam_benchmark_spec(n_areas=4, n_per_area=64, k_intra=4200,
+                              k_inter=4200, rate_hz=2000.0)
+    net = build_network(spec, seed=5, outgoing=True)
+    assert ops.deposit_chunk(net.tgt_intra.shape[-1], 126) == 8
+    engines = [make_simulation(spec, EngineConfig(
+        neuron_model="ignore_and_fire", delivery_backend=backend,
+        s_max_floor=24, **kw), net=net) for backend in ("event", "scatter")]
+    event, scatter = engines
+    assert ops.DEPOSIT_CHUNK_SCOPE in event.window.lower(
+        event.init()).as_text(debug_info=True)
+    se, ss = event.init(), scatter.init()
+    for w in range(5):
+        se, be = event.window(se)
+        ss, bs = scatter.window(ss)
+        assert np.array_equal(np.asarray(be), np.asarray(bs)), w
+        assert np.array_equal(np.asarray(se.ring), np.asarray(ss.ring)), w
+        assert np.asarray(se.ring).any(), w
+    assert int(se.overflow) == 0
+    assert int(se.spike_count.sum()) > 2000

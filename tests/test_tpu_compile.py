@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.engine import ConfigError, EngineConfig
-from repro.kernels import cycle, lif_update, spike_deliver
+from repro.kernels import cycle, lif_update, ops, spike_deliver
 
 # Full-width shapes: the MAM benchmark at its published in-degrees
 # (K = 3000 per pathway) cut to 8 areas x 4096 neurons -- one chip's share.
@@ -101,6 +101,32 @@ def _superstep_iaf(one_chip):
                           steps_lo=STEPS_LO, r_span=R_SPAN, interpret=False),
         one_chip, row_i, ((N_AREAS, N_PER_AREA, w_width), jnp.float32),
         row_i, row_i, (syn, jnp.int32), (syn, jnp.float32), (syn, jnp.int32))
+
+
+# The benchmark cell's event deposits (mam_bench.ground: 8 x 3,072 neurons,
+# outgoing tables widened to 3,337, static packet bounds 1,030 per area and
+# 4,145 per cycle, int8 delays): the intra deposit runs each area's packet
+# into its 40-slot ring, the window-end inter deposit takes the window's 10
+# packets into the 110-slot ring.
+CELL_N, CELL_K_OUT = 3072, 3337
+
+
+@pytest.mark.parametrize("fn,ring,ids,table", [
+    (ops.event_deliver_per_area, (N_AREAS, CELL_N, 40), (N_AREAS, 1030),
+     (N_AREAS, CELL_N, CELL_K_OUT)),
+    (ops.event_deliver_block, (N_AREAS * CELL_N, 110), (D, 4145),
+     (N_AREAS * CELL_N, CELL_K_OUT)),
+], ids=["intra", "inter"])
+def test_chunked_event_deposit_compiles_for_v5e(one_chip, fn, ring, ids,
+                                                table):
+    """The event deposit's chunk loop (a data-dependent ``while`` over
+    ``dynamic_slice``d packet columns) at the cell's shapes."""
+    assert ops.deposit_chunk(CELL_K_OUT, ids[-1]) < ids[-1]
+    compiled = _compile(
+        fn, one_chip, (ring, jnp.float32), (ids, jnp.int32),
+        (table, jnp.int32), (table, jnp.float32), (table, jnp.int8),
+        ((), jnp.int32))
+    assert "while" in compiled.as_text()
 
 
 @pytest.mark.parametrize("variant,error", [
